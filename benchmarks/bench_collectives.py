@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.launch import machine, telemetry
 
 # Payload sizes (f32 elements) spanning the latency- and bandwidth-bound
@@ -49,7 +48,7 @@ def _psum_fn(mesh, n_elems: int):
     def body(v):
         return jax.lax.psum(v, "data")
 
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
                                  in_specs=P("data", None),
                                  out_specs=P(None, None)))
     n = mesh.shape["data"]

@@ -36,6 +36,8 @@ def main() -> None:
                          "collectives|precision")
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_svd, bench_optim, bench_gemm, bench_sparse,
                             bench_autotune, bench_planner, bench_serve,
                             bench_collectives, bench_precision)

@@ -10,6 +10,9 @@ import numpy as np
 from repro.core.distmat import RowMatrix
 from repro.core.tfocs import (LinopMatrix, SmoothQuad, ProxL1, tfocs,
                               TfocsOptions)
+from repro import compile_cache
+
+compile_cache.enable()
 
 rng = np.random.default_rng(1)
 m, n = 2000, 256

@@ -6,6 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tfocs import solve_smoothed_lp, TfocsOptions
+from repro import compile_cache
+
+compile_cache.enable()
 
 rng = np.random.default_rng(3)
 m, n = 20, 60

@@ -12,6 +12,9 @@ import numpy as np
 from repro.core.distmat import RowMatrix, CoordinateMatrix, SparseRowMatrix
 from repro.core.linalg import compute_svd, tsqr
 from repro.core.tfocs import solve_lasso, TfocsOptions
+from repro import compile_cache
+
+compile_cache.enable()
 
 rng = np.random.default_rng(0)
 

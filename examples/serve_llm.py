@@ -22,6 +22,9 @@ from repro import configs
 from repro.launch.mesh import make_host_mesh
 from repro.models import build, smoke_config
 from repro.models.sharding import use_mesh
+from repro import compile_cache
+
+compile_cache.enable()
 
 
 def main() -> None:

@@ -19,6 +19,9 @@ import numpy as np
 
 from repro.core.distmat import CoordinateMatrix, RowMatrix
 from repro.core.linalg import compute_svd
+from repro import compile_cache
+
+compile_cache.enable()
 
 rng = np.random.default_rng(0)
 

@@ -19,6 +19,9 @@ from repro.train import checkpoint as ckpt
 from repro.train import optimizer as opt_mod
 from repro.train.straggler import StepMonitor
 from repro.train.train_step import build_train_step
+from repro import compile_cache
+
+compile_cache.enable()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=300)

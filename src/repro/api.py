@@ -48,6 +48,7 @@ from repro.core.tfocs.linop import LinopMatrix
 from repro.core.tfocs.prox import ProxL1, ProxL2Sq, ProxZero
 from repro.core.tfocs.smooth import (SmoothHuber, SmoothLogLoss,
                                      SmoothPoisson, SmoothQuad)
+from repro.kernels import autotune as _autotune
 from repro.kernels.fusedgrad import LOSSES
 
 Array = jax.Array
@@ -266,20 +267,29 @@ def solve_prox(req: SolveRequest):
 
 # -- direct call path ---------------------------------------------------------
 
+# Every job computes in float32, at the kernels' own precision: XLA's
+# default on TPU multiplies f32 matrices in one bf16 pass, which the Lanczos
+# reorthogonalization, the solvers' inner products and the dense matvecs
+# cannot afford.
+F32_MATMULS = _autotune.HIGHEST.name.lower()
+
+
 def _traced(req, kind: str, run) -> Result:
-    """The ``telemetry=`` escape hatch: when the request asks for it, run
-    the job under a scoped recorder (every instrumented component —
-    elastic iterations, checkpoints, stragglers — resolves it via
-    telemetry.current()) and attach the compact summary as
+    """Run one job with f32 matmuls.  The ``telemetry=`` escape hatch: when
+    the request asks for it, run the job under a scoped recorder (every
+    instrumented component — elastic iterations, checkpoints, stragglers —
+    resolves it via telemetry.current()) and attach the compact summary as
     ``Result.info["trace"]``.  Off (the default) adds no work at all."""
-    if not req.telemetry:
-        return run()
-    from repro.launch import telemetry as _telemetry
-    rec = req.telemetry if isinstance(req.telemetry, _telemetry.Recorder) \
-        else _telemetry.Recorder()
-    with _telemetry.recording(rec):
-        with rec.span("api." + kind, request_id=req.request_id):
-            res = run()
+    with jax.default_matmul_precision(F32_MATMULS):
+        if not req.telemetry:
+            return run()
+        from repro.launch import telemetry as _telemetry
+        rec = req.telemetry \
+            if isinstance(req.telemetry, _telemetry.Recorder) \
+            else _telemetry.Recorder()
+        with _telemetry.recording(rec):
+            with rec.span("api." + kind, request_id=req.request_id):
+                res = run()
     res.info["trace"] = rec.summary()
     return res
 

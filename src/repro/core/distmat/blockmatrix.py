@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from . import types as T
 
 Array = jax.Array
@@ -86,8 +85,7 @@ class BlockMatrix(T.DistMatrix):
             raise ValueError(f"bad sharding {got}, want {want}")
 
     def _smap(self, f, in_specs, out_specs):
-        return compat.shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                                out_specs=out_specs)
+        return T.shard_map(f, self.mesh, in_specs, out_specs)
 
     @property
     def _spec(self) -> P:

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from . import types as T
 
 Array = jax.Array
@@ -58,8 +57,7 @@ class CoordinateMatrix(T.DistMatrix):
         return self.dims
 
     def _smap(self, f, in_specs, out_specs):
-        return compat.shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                                out_specs=out_specs)
+        return T.shard_map(f, self.mesh, in_specs, out_specs)
 
     def matvec(self, v: Array) -> Array:
         """A v: gather v at col indices, segment-sum into rows, all-reduce."""

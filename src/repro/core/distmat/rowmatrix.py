@@ -13,6 +13,7 @@ All cluster/driver separation from the paper is explicit here:
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field, replace
 from typing import Sequence
 
@@ -21,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from . import types as T
 
 Array = jax.Array
@@ -31,7 +31,7 @@ def _shard_index(axes: Sequence[str]) -> Array:
     """Flat index of this shard along the given (major→minor) mesh axes."""
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -53,6 +53,10 @@ def _record_collective(plan, span, **attrs) -> None:
         rec.record_plan_actual(plan, span.dur_s, **attrs)
 
 
+# A pytree over its device array, so jitted code takes the matrix as an
+# argument instead of baking it into the executable as a constant.
+@functools.partial(jax.tree_util.register_dataclass, data_fields=["rows"],
+                   meta_fields=["n_rows", "mesh", "row_axes"])
 @dataclass(frozen=True)
 class RowMatrix(T.DistMatrix):
     rows: Array                      # (m_padded, n), sharded P(row_axes, None)
@@ -105,8 +109,7 @@ class RowMatrix(T.DistMatrix):
         return P(self.row_axes, None)
 
     def _smap(self, f, in_specs, out_specs):
-        return compat.shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                                out_specs=out_specs)
+        return T.shard_map(f, self.mesh, in_specs, out_specs)
 
     def _local_rows(self) -> int:
         return self.rows.shape[0] // T.axes_size(self.mesh, self.row_axes)

@@ -29,6 +29,7 @@ recovers the exact scaled-Gram similarity.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field, replace
 from typing import Sequence
@@ -38,7 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.kernels import bsr as _bsr
 from . import types as T
 from .rowmatrix import RowMatrix, _shard_index
@@ -100,6 +100,10 @@ def _auto_block_size(a: np.ndarray, nx_hint: int) -> int:
     return _best_block_size(a.shape, a.dtype, ell_of_bs, nx_hint)
 
 
+# A pytree over its device arrays (see RowMatrix).
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["data", "cols", "scales"],
+                   meta_fields=["dims", "nnz", "mesh", "row_axes"])
 @dataclass(frozen=True)
 class SparseRowMatrix(T.DistMatrix):
     data: Array                 # (nbr_pad, ell, bs, bs), sharded P(row_axes)
@@ -184,8 +188,7 @@ class SparseRowMatrix(T.DistMatrix):
         cols[ubi, slot] = ubj
         sh = NamedSharding(mesh, P(row_axes))
         nnz = int(np.count_nonzero(blocks))
-        return SparseRowMatrix(T.put(jnp.asarray(data), sh),
-                               T.put(jnp.asarray(cols), sh),
+        return SparseRowMatrix(T.put(data, sh), T.put(cols, sh),
                                dims=(m, n), nnz=nnz, mesh=mesh,
                                row_axes=row_axes)
 
@@ -287,8 +290,7 @@ class SparseRowMatrix(T.DistMatrix):
         return replace(out, data=out.data.astype(dtype))
 
     def _smap(self, f, in_specs, out_specs):
-        return compat.shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                                out_specs=out_specs)
+        return T.shard_map(f, self.mesh, in_specs, out_specs)
 
     @property
     def _dspec(self) -> P:

@@ -17,9 +17,9 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 Array = jax.Array
 
@@ -33,11 +33,25 @@ COL_AXIS = "model"
 @functools.cache
 def single_device_mesh() -> Mesh:
     """A (1, 1) mesh so the same shard_map code path runs on one CPU."""
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
-def make_mesh(shape: Sequence[int], names: Sequence[str]) -> Mesh:
-    return compat.make_mesh(shape, names)
+def make_mesh(shape: Sequence[int], names: Sequence[str],
+              devices: Sequence | None = None) -> Mesh:
+    """A mesh with Auto axis types over `devices` (the first
+    prod(shape) devices by default)."""
+    names = tuple(names)
+    return jax.make_mesh(tuple(shape), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         devices=devices)
+
+
+def shard_map(f: Callable, mesh: Mesh, in_specs, out_specs) -> Callable:
+    """jax.shard_map for the distmat bodies.  The Pallas kernels they call
+    declare no varying mesh axes on their outputs, so the varying-axes
+    check is off (the out_specs state the layout)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def row_axes_for(mesh: Mesh) -> tuple[str, ...]:
@@ -68,8 +82,12 @@ def block_sharding(mesh: Mesh, row_axes: Sequence[str] | None = None,
 
 
 def put(x: Array, sharding: NamedSharding) -> Array:
-    """Place `x` with `sharding` (device_put works inside or outside jit)."""
-    return jax.device_put(jnp.asarray(x), sharding)
+    """Place `x` with `sharding` (device_put works inside or outside jit).
+    A host array goes shard by shard to its devices, never through one
+    device whole."""
+    if not isinstance(x, (jax.Array, np.ndarray)):
+        x = jnp.asarray(x)
+    return jax.device_put(x, sharding)
 
 
 def pad_rows(x: Array, multiple: int) -> tuple[Array, int]:

@@ -40,6 +40,7 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.distmat.coordinatematrix import CoordinateMatrix
 from repro.core.distmat.rowmatrix import RowMatrix
@@ -65,6 +66,16 @@ class SVDResult:
     s: Array                # (k,) singular values, descending (replicated)
     V: Array                # (n, k) right singular vectors (replicated)
     info: dict | None = None
+
+
+def driver_eigh(G: Array, k: int | None = None) -> tuple[Array, Array]:
+    """Eigenpairs of the replicated n × n Gram, largest first (top k), on
+    the driver: LAPACK in float64 on the host, as the paper runs it.  (The
+    TPU's own eigh compiles for minutes and tens of GB of host memory at
+    n in the thousands.)"""
+    w, V = np.linalg.eigh(np.asarray(jax.device_get(G), np.float64))
+    w, V = w[::-1][:k], V[:, ::-1][:, :k]
+    return jnp.asarray(w, jnp.float32), jnp.asarray(V, jnp.float32)
 
 
 def _recover_u(A, s: Array, V: Array, rcond: float) -> RowMatrix:
@@ -158,10 +169,8 @@ def compute_svd(A, k: int, *, compute_u: bool = True,
     # deprecated aliases kept for one release.
     if mode == "gram":
         # §3.1.2 tall-and-skinny: one all-reduce builds AᵀA, the
-        # eigendecomposition is a driver-local (replicated) op.
-        G = A.gram().astype(jnp.float32)
-        w, V = jnp.linalg.eigh(G)
-        w, V = w[::-1][:k], V[:, ::-1][:, :k]
+        # eigendecomposition runs on the driver.
+        w, V = driver_eigh(A.gram(), k)
         s = jnp.sqrt(jnp.maximum(w, 0.0))
         info = {"mode": "gram", "plan": "gram", "iterations": 0,
                 "a_passes": 1, "converged": True}
@@ -201,6 +210,5 @@ def compute_pca(A: RowMatrix, k: int) -> tuple[Array, Array]:
     mu = stats["mean"]
     G = A.gram().astype(jnp.float32)
     cov = (G - m * jnp.outer(mu, mu)) / max(m - 1, 1)
-    w, V = jnp.linalg.eigh(cov)
-    w, V = w[::-1][:k], V[:, ::-1][:, :k]
+    w, V = driver_eigh(cov, k)
     return V, jnp.maximum(w, 0.0)

@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.distmat import types as T
 from repro.core.distmat.rowmatrix import RowMatrix
 
@@ -39,8 +38,7 @@ def tsqr(A: RowMatrix) -> tuple[RowMatrix, Array]:
         r = jnp.linalg.qr(a, mode="r")
         return _nonneg_diag(r)
 
-    Rs = compat.shard_map(local_r, mesh=mesh, in_specs=(spec,),
-                          out_specs=spec)(A.rows)    # (P·n, n) row-sharded
+    Rs = T.shard_map(local_r, mesh, (spec,), spec)(A.rows)    # (P·n, n) row-sharded
     # Reduce step: replicated second-level QR of the stacked R factors.
     R = _nonneg_diag(jnp.linalg.qr(
         T.put(Rs, T.replicated(mesh)), mode="r"))
@@ -60,7 +58,6 @@ def tsqr(A: RowMatrix) -> tuple[RowMatrix, Array]:
     def recover_q(a, ri):
         return _ops.gemm(a, ri, out_dtype=a.dtype)
 
-    Q = compat.shard_map(recover_q, mesh=mesh, in_specs=(spec, P()),
-                         out_specs=spec)(A.rows, r_inv)
+    Q = T.shard_map(recover_q, mesh, (spec, P()), spec)(A.rows, r_inv)
     from dataclasses import replace
     return replace(A, rows=Q), R

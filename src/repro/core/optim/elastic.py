@@ -37,6 +37,7 @@ resumable solves" section of examples/quickstart.py for the wiring.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable
 
@@ -218,6 +219,15 @@ def _find_hook(linop):
     return None
 
 
+def _with_matrix(linop, A):
+    """`linop` with its matrix operand replaced by `A`, through any chain
+    of delegating wrappers (dataclasses whose `base` is the wrapped
+    operator)."""
+    if hasattr(linop, "base"):
+        return dataclasses.replace(linop, base=_with_matrix(linop.base, A))
+    return dataclasses.replace(linop, A=A)
+
+
 class ElasticGroup:
     """Host-driven executor for one batched solver group, one iteration at
     a time — the state-holder behind launch/serve.GroupRunner and
@@ -311,19 +321,25 @@ class ElasticGroup:
                 "fusedgrad", {"m": self.m_pad, "n": self.n})
         return self._fused_plan_cache
 
-    def _build_engines(self) -> None:
+    def _engines(self, linop):
         if self.method == "gra":
-            seed, step = _batched.make_gra_group(self.linop, self.kind,
-                                                 self.param, reg=self.reg)
-        elif self.method in ACC_METHODS:
+            return _batched.make_gra_group(linop, self.kind, self.param,
+                                           reg=self.reg)
+        if self.method in ACC_METHODS:
             rb = self.method == "acc_rb"
-            seed, step = _batched.make_acc_group(
-                self.linop, self.kind, self.param, reg=self.reg,
+            return _batched.make_acc_group(
+                linop, self.kind, self.param, reg=self.reg,
                 backtracking=rb, restart=rb)
-        else:
-            seed, step = _batched.make_lbfgs_group(self.linop, self.kind,
-                                                   self.param)
-        self._seed, self._step = jax.jit(seed), jax.jit(step)
+        return _batched.make_lbfgs_group(linop, self.kind, self.param)
+
+    def _build_engines(self) -> None:
+        # The matrix is a jit argument: closed over, it would be baked into
+        # each executable as a constant.
+        def engine(i):
+            return jax.jit(lambda A, *a: self._engines(
+                _with_matrix(self.linop, A))[i](*a))
+        self._seed = functools.partial(engine(0), self.linop.A)
+        self._step = functools.partial(engine(1), self.linop.A)
 
     # -- slot management ------------------------------------------------------
 

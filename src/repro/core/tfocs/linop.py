@@ -121,6 +121,13 @@ class LinopMatrix:
             return A.data.dtype
         return jnp.asarray(A).dtype
 
+    def block_layout(self) -> tuple[int, int] | None:
+        """(bs, ell) of a block-sparse operand's BlockELL shards — the
+        planner prices its fused gradient on the stored blocks — or None
+        for a dense one."""
+        A = self.A
+        return (A.bs, A.ell) if isinstance(A, SparseRowMatrix) else None
+
     def row_shards(self) -> int:
         """Number of row shards the operand is split into — the fused-vs-
         unfused roofline is a per-shard decision, so the dispatch divides
@@ -228,6 +235,9 @@ class CountingLinop:
 
     def row_shards(self) -> int:
         return self.base.row_shards()
+
+    def block_layout(self) -> tuple[int, int] | None:
+        return self.base.block_layout()
 
     def pad_data(self, b: Array) -> Array:
         return self.base.pad_data(b)

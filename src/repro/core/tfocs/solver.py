@@ -53,6 +53,23 @@ from .smooth import row_separable
 Array = jax.Array
 
 
+# Relative objective change below which the backtracking test stops
+# comparing f32 objective values (their difference is then rounding noise)
+# and tests the local Lipschitz estimate 2⟨∇f(x⁺) − ∇f(y), x⁺ − y⟩ /
+# ‖x⁺ − y‖² ≤ L instead — TFOCS's own switch (its `backtrack_tol`), at f32
+# resolution.
+BACKTRACK_TOL = 1e-5
+
+
+def _backtrack_ok(f_new, f_y, rhs, curvature, L, xy_sq):
+    """TFOCS's sufficient-decrease test: f(x⁺) ≤ rhs while the objective
+    values differ by more than rounding, the gradient-based curvature
+    ⟨∇f(x⁺) − ∇f(y), x⁺ − y⟩ ≤ L/2‖x⁺ − y‖² once they do not."""
+    simple = jnp.abs(f_y - f_new) >= BACKTRACK_TOL * jnp.maximum(
+        jnp.abs(f_new), jnp.abs(f_y))
+    return jnp.where(simple, f_new <= rhs, 2.0 * curvature <= L * xy_sq)
+
+
 @dataclass(frozen=True)
 class TfocsOptions:
     max_iters: int = 500
@@ -123,11 +140,18 @@ def fused_gradient_enabled(smooth, linop, fused: bool | str = "auto",
         # The roofline compares per-shard streaming passes, so price the
         # shard, not the global row count (lane-padding waste is per shard).
         shards = linop.row_shards() if hasattr(linop, "row_shards") else 1
+        layout = linop.block_layout() if hasattr(linop, "block_layout") \
+            else None
     except (AttributeError, TypeError):
         return True
     from repro.launch import planner as _planner
-    return _planner.plan("grad", {"m": max(m // max(shards, 1), 1), "n": n},
-                         dtype).choice == "fused"
+    dims = {"m": max(m // max(shards, 1), 1), "n": n}
+    if layout is None:
+        return _planner.plan("grad", dims, dtype).choice == "fused"
+    # A block-sparse operand: the BSR fused kernel against its two passes.
+    bs, ell = layout
+    return _planner.plan("grad", {**dims, "bs": bs, "ell": ell}, dtype,
+                         context={"kind": "sparse"}).choice == "fused"
 
 
 _PRECISIONS = ("auto", "f32", "bf16", "psum8")
@@ -258,8 +282,10 @@ def _tfocs_fused(smooth, linop, prox, x0: Array, opts: TfocsOptions,
         x_new = prox.prox(state.x - step * state.g, step)
         f_new, g_new, res_new = fg(x_new, state.res)         # ← ONE A-pass
         dx = x_new - state.x
-        rhs = state.f + jnp.vdot(state.g, dx) + 0.5 * a.L * jnp.vdot(dx, dx)
-        ok = f_new <= rhs + 1e-12 * jnp.abs(state.f)
+        xy_sq = jnp.vdot(dx, dx)
+        rhs = state.f + jnp.vdot(state.g, dx) + 0.5 * a.L * xy_sq
+        ok = _backtrack_ok(f_new, state.f, rhs, jnp.vdot(g_new - state.g, dx),
+                           a.L, xy_sq)
         return a._replace(x=x_new, f=f_new, g=g_new, ok=ok,
                           tries=a.tries + 1, res=res_new)
 
@@ -521,8 +547,11 @@ def tfocs(smooth, linop, prox, x0: Array,
         Ax_new = (1 - a.theta) * a.Ax + a.theta * Az_new
         f_new = smooth.value(Ax_new)
         dx = x_new - y
-        rhs = fy + jnp.vdot(gy, Ax_new - Ay) + 0.5 * a.L * jnp.vdot(dx, dx)
-        ok = f_new <= rhs + 1e-12 * jnp.abs(fy)
+        xy_sq = jnp.vdot(dx, dx)
+        rhs = fy + jnp.vdot(g, dx) + 0.5 * a.L * xy_sq
+        ok = _backtrack_ok(
+            f_new, fy, rhs,
+            jnp.vdot(smooth.grad(Ax_new) - gy, Ax_new - Ay), a.L, xy_sq)
         return a._replace(x=x_new, Ax=Ax_new, z=z_new, Az=Az_new,
                           fy=fy, gy=gy, Ay=Ay, ok=ok, tries=a.tries + 1)
 

@@ -8,8 +8,11 @@ replaces those magic numbers with a three-stage, shape-aware search:
   1. **Candidate generation** — enumerate tile configs that satisfy the TPU
      layout rules (last dim a multiple of 128 lanes; second-to-last a
      multiple of the dtype sublane count: 8 for f32, 16 for bf16, 32 for
-     int8/fp8) and whose double-buffered VMEM working set fits the budget
-     (`VMEM_BUDGET`, a headroom fraction of the 16 MB/core VMEM).
+     int8/fp8) and whose VMEM working set — every pipelined block counted
+     twice (Pallas double-buffers inputs AND outputs), (1, n) rows counted
+     at their 8-sublane padding — fits `VMEM_BUDGET`, a headroom fraction
+     of the scoped limit `VMEM_LIMIT` that every kernel requests from the
+     compiler through `compiler_params()`.
 
   2. **Analytical roofline pre-ranking** — order candidates by a cost model:
      max(MXU time at the tile's utilization, HBM bytes / bandwidth) computed
@@ -47,6 +50,7 @@ from typing import Callable, Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
+import jax.experimental.pallas.tpu as pltpu
 
 from repro.launch import machine as _machine
 from repro.launch.machine import LANE, SUBLANE_BY_ITEMSIZE, CostTerms
@@ -59,7 +63,29 @@ from repro.launch.machine import LANE, SUBLANE_BY_ITEMSIZE, CostTerms
 # them.
 
 VMEM_BYTES = _machine.V5E.vmem_bytes
-VMEM_BUDGET = int(VMEM_BYTES * 0.85)       # headroom for semaphores/spills
+# The compiler's default scoped VMEM limit for a kernel (16 MiB on v5e) is
+# far below the physical VMEM; every kernel asks for VMEM_LIMIT explicitly
+# and the working-set estimates below budget against it, leaving the rest
+# of the core's VMEM to the compiler's own scratch.
+VMEM_LIMIT = VMEM_BYTES * 3 // 4
+VMEM_BUDGET = int(VMEM_LIMIT * 0.9)       # headroom for semaphores/spills
+# f32 words of in-kernel temporaries per element of a streamed A block: an
+# f32-precision contraction splits its operands into bf16 parts, and a
+# row-vector product transposes the block.  Fitted to the v5e compiler's
+# own accounting (fused_grad uses ~6 block-sizes of VMEM: 2 buffers + 4).
+F32_TEMPS = 4
+
+
+# Every contraction of this package runs at full f32 precision on the MXU
+# (XLA's TPU default multiplies f32 matrices in one bf16 pass); the api
+# sets the same precision as the default around every job it runs.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    """The Mosaic compiler params every kernel of this package uses."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_LIMIT)
 
 
 def sublane(dtype) -> int:
@@ -73,6 +99,11 @@ def _rup(x: int, m: int) -> int:
 
 def _itemsize(dtype) -> int:
     return jnp.dtype(dtype).itemsize
+
+
+def _rows(r: int) -> int:
+    """VMEM rows a (r, ·) f32 block occupies: rounded up to 8 sublanes."""
+    return _rup(max(r, 1), 8)
 
 
 def _util(b: int) -> float:
@@ -137,35 +168,67 @@ def _gemm_terms(b, d, dtype):
                      mxu_util=_util(b["bm"]))
 
 
+def _tsgram_bn(b, d) -> int:
+    """The output-tile width the kernel runs with: the knob clamped to the
+    lane-padded n and rounded down to a divisor of it (so A's columns
+    never need padding)."""
+    np_ = _rup(d["n"], LANE)
+    full = np_ if np_ <= TSGRAM_FULL_MAX else TSGRAM_MAX_TILE
+    bn = min(_rup(b.get("bn", full), LANE), np_)
+    while np_ % bn:
+        bn -= LANE
+    return bn
+
+
 def _tsgram_vmem(b, d, dtype):
     db = _itemsize(dtype)
-    np_ = _rup(d["n"], LANE)
-    return 2 * b["bm"] * np_ * db + np_ * np_ * 4 + np_ * np_ * db
+    bn = _tsgram_bn(b, d)
+    ops = 1 if bn == _rup(d["n"], LANE) else 2      # one or two A operands
+    return (ops * b["bm"] * bn * (2 * db + F32_TEMPS * 4)   # A blocks
+            + 4 * bn * bn * 4)      # f32 accumulator, product, output x2
+
+
+# Grams up to TSGRAM_FULL_MAX wide are one output tile (A read once);
+# wider ones run in tiles of at most TSGRAM_MAX_TILE (bigger f32 tiles
+# compile slowly — several seconds per doubling — and gain nothing on a
+# compute-bound kernel).
+TSGRAM_FULL_MAX = 1024
+TSGRAM_MAX_TILE = 512
 
 
 def _tsgram_gen(d, dtype):
     sub = sublane(dtype)
+    np_ = _rup(d["n"], LANE)
+    full = [np_] if np_ <= TSGRAM_FULL_MAX else []
+    tiles = sorted({_tsgram_bn({"bn": c}, d)
+                    for c in [128, 256, TSGRAM_MAX_TILE] + full})
     out = []
     for bm in _steps(d["m"], sub, (sub, 64, 128, 256, 512, 1024)):
-        b = {"bm": bm}
-        if _tsgram_vmem(b, d, dtype) <= VMEM_BUDGET:
-            out.append(b)
+        for bn in tiles:
+            b = {"bm": bm, "bn": bn}
+            if _tsgram_vmem(b, d, dtype) <= VMEM_BUDGET:
+                out.append(b)
     return out
 
 
 def _tsgram_terms(b, d, dtype):
+    """(bn × bn) output tiles: tile (i, j) streams column blocks i and j
+    of every row block, so A is read once when bn covers n and 2·n/bn
+    times otherwise — narrow tiles trade HBM traffic for VMEM."""
     db = _itemsize(dtype)
     mp, np_ = _rup(d["m"], b["bm"]), _rup(d["n"], LANE)
-    hbm = mp * np_ * db + np_ * np_ * db        # one pass over A + G out
+    t = np_ // _tsgram_bn(b, d)
+    a_reads = 1 if t == 1 else 2 * t
+    hbm = mp * np_ * db * a_reads + np_ * np_ * db
     return CostTerms(flops=2.0 * mp * np_ * np_, hbm_bytes=hbm,
-                     steps=mp // b["bm"], mxu_util=_util(b["bm"]))
+                     steps=t * t * (mp // b["bm"]), mxu_util=_util(b["bm"]))
 
 
 def _randsketch_vmem(b, d, dtype):
     db = _itemsize(dtype)
     rp = _rup(d["r"], LANE)
     return (2 * (b["bm"] * b["bn"] + b["bm"] * rp) * db
-            + b["bn"] * rp * 4 + b["bn"] * rp * db)
+            + b["bn"] * rp * 4 + 2 * b["bn"] * rp * 4)
 
 
 def _randsketch_gen(d, dtype):
@@ -192,12 +255,17 @@ def _randsketch_terms(b, d, dtype):
 
 
 def _fusedgrad_vmem(b, d, dtype):
+    """Single-RHS kernel at k=1; the multi-RHS kernel at k = kp requests
+    (d["k"]): x, g and the t/w/z strips are (k, ·) blocks padded to 8
+    sublanes, every pipelined block double-buffered."""
     db = _itemsize(dtype)
     np_ = _rup(d["n"], LANE)
-    return (2 * b["bm"] * np_ * db       # A row-block stream, double-buffered
-            + np_ * db                   # resident x row
-            + 4 * 2 * b["bm"] * db       # t, w, z (1 × bm) strips
-            + np_ * 4 + np_ * 4)         # g accumulator + g out (f32)
+    k = _rows(d.get("k", 1))
+    return (b["bm"] * np_ * (2 * db + F32_TEMPS * 4)    # A row blocks
+            + 2 * k * np_ * 4            # x rows
+            + 3 * 2 * k * b["bm"] * 4    # t, w, z strips
+            + 3 * k * np_ * 4            # g accumulator + g out
+            + 3 * k * LANE * 4)          # f accumulator + f out
 
 
 def _fusedgrad_gen(d, dtype):
@@ -300,12 +368,15 @@ def _bsr_ell(bs: int, d) -> int:
 
 
 def _bsr_vmem(b, d, dtype):
+    """Per-slot SpMM working set (A block + gathered X block in, output
+    block-row out, f32 accumulator)."""
     db = _itemsize(dtype)
     bs = b["bs"]
+    bsl, bsp = _rup(bs, sublane(dtype)), _rup(bs, LANE)
     nxp = _rup(max(d.get("nx", 1), 1), LANE)
-    return (2 * bs * bs * db + 2 * bs * nxp * db     # A block + X block streams
-            + bs * nxp * 4                           # f32 acc scratch
-            + 2 * bs * nxp * db)                     # out tile
+    return (2 * bsl * bsp * db + 2 * bsl * nxp * db   # A block + X block
+            + bsl * nxp * 4                           # f32 acc scratch
+            + 2 * bsl * nxp * db)                     # out tile
 
 
 def _bsr_gen(d, dtype):
@@ -341,13 +412,13 @@ KERNELS: dict[str, KernelSpec] = {
     "gemm": KernelSpec(("bm", "bn", "bk"), ("m", "k", "n"),
                        {"bm": 256, "bn": 256, "bk": 512},
                        _gemm_gen, _gemm_vmem, _gemm_terms),
-    "tsgram": KernelSpec(("bm",), ("m", "n"), {"bm": 512},
+    "tsgram": KernelSpec(("bm", "bn"), ("m", "n"), {"bm": 512, "bn": 512},
                          _tsgram_gen, _tsgram_vmem, _tsgram_terms),
     "randsketch": KernelSpec(("bm", "bn"), ("m", "n", "r"),
                              {"bm": 512, "bn": 512},
                              _randsketch_gen, _randsketch_vmem,
                              _randsketch_terms),
-    "fusedgrad": KernelSpec(("bm",), ("m", "n"), {"bm": 512},
+    "fusedgrad": KernelSpec(("bm",), ("m", "n", "k"), {"bm": 512},
                             _fusedgrad_gen, _fusedgrad_vmem,
                             _fusedgrad_terms),
     "flash_attention": KernelSpec(("bq", "bk"), ("sq", "sk", "d", "causal"),
@@ -393,14 +464,15 @@ def rank(kernel: str, dims: Mapping[str, int], dtype, *,
          ) -> list[tuple[float, dict]]:
     """(score, blocks) ascending by model time; deterministic tie-break.
 
-    The legacy hand-picked config is always in the pool (even when the VMEM
-    estimate is conservative enough to exclude it), so the selected config
-    can never score worse than the old constants.
+    The legacy hand-picked config joins the pool whenever its VMEM working
+    set fits the budget, so the selected config never scores worse than
+    the old constants where those could run at all.
     """
     machine = machine or _machine.for_backend()
     pool = candidates(kernel, dims, dtype)
     legacy = dict(KERNELS[kernel].legacy)
-    if legacy not in pool:
+    if legacy not in pool \
+            and estimate_vmem(kernel, legacy, dims, dtype) <= VMEM_BUDGET:
         pool = pool + [legacy]
     scored = [(model_time(kernel, b, dims, dtype, machine=machine), b)
               for b in pool]
@@ -418,7 +490,9 @@ def bucket(x: int) -> int:
 def cache_key(kernel: str, backend: str, dtype,
               dims: Mapping[str, int]) -> str:
     spec = KERNELS[kernel]
-    shape = "x".join(str(bucket(int(dims[k]))) for k in spec.dims)
+    # A dim the caller leaves out takes its unit value (fusedgrad's request
+    # count k is 1 for the single-RHS kernel).
+    shape = "x".join(str(bucket(int(dims.get(k, 1)))) for k in spec.dims)
     return f"{kernel}|{backend}|{jnp.dtype(dtype).name}|{shape}"
 
 
@@ -555,7 +629,8 @@ def sweep(kernel: str, dims: Mapping[str, int], dtype,
     ranked = rank(kernel, dims, dtype)
     pool = [blocks for _, blocks in ranked[:top_n]]
     legacy = dict(KERNELS[kernel].legacy)
-    if include_legacy and legacy not in pool:
+    if include_legacy and legacy not in pool \
+            and legacy in [b for _, b in ranked]:
         pool.append(legacy)
     stats["swept"] += 1
     timed = []

@@ -7,24 +7,30 @@ sparse format is block-CSR padded to ELL: every block-row holds a fixed
 number of (bs × bs) blocks (zero-padded — a zero block contributes nothing,
 which removes all control flow from the kernel).
 
-The column indices live in SMEM via scalar prefetch, and the index_map
-*gathers the needed X block directly* — the kernel body is one dense MXU
-matmul per block, i.e. sparsity is handled entirely by the grid machinery.
+Every kernel stages one block-row's ell stored blocks per grid step.  The
+row's column list rides along as a small SMEM block, so the per-block
+column offsets are scalar reads that scale with ell, not with the matrix:
 
-Three kernels share the layout:
-
-  * ``bsr_matmul``  — y = A @ X   (SpMM, gathers X blocks by column index);
-  * ``bsr_matvec``  — y = A @ x   (SpMV: x stored block-partitioned, the
-    block product is a (1 × bs)·(bs × bs) row-vector matmul on the MXU);
+  * ``bsr_matmul``  — y = A @ X   (SpMM: the X block of each slot is
+    gathered by the index_map from scalar-prefetched column indices; the
+    prefetch runs in chunks of block-rows sized to fit SMEM);
+  * ``bsr_matvec``  — y = A @ x   (SpMV: x stays resident in VMEM as an
+    (nbc × bs) array, each slot reads its row at the block-column offset,
+    and the block product is a (1 × bs)·(bs × bs) row-vector matmul on the
+    MXU);
   * ``bsr_rmatmul`` — y = Aᵀ @ X  (transpose-multiply: the scatter-add over
     block columns is fused into the kernel — the full (nbc × bs × nx)
     accumulator stays resident in VMEM and each per-block partial Aᵢⱼᵀ Xᵢ
-    is added at the dynamic offset cols[i, slot] as soon as it is computed.
-    The grid is sequential ("arbitrary" on both axes), so the read-modify-
-    write is race-free and no HBM partials buffer is needed.  When the
-    resident accumulator would overflow the VMEM budget (n·nx too large)
-    the kernel falls back to emitting (nbr·ell, bs, nx) partials + one XLA
-    segment_sum — the old scheme, kept for the wide regime).
+    is added at offset cols[i, slot] as soon as it is computed.  The grid
+    is sequential ("arbitrary"), so the read-modify-write is race-free and
+    no HBM partials buffer is needed.  When the resident accumulator would
+    overflow the VMEM budget (n·nx too large) the kernel falls back to
+    emitting (nbr, ell, bs, nx) partials + one XLA segment_sum — the old
+    scheme, kept for the wide regime).
+
+Per-block-row vectors (z, t, w, the column lists) travel as (nbr, 1, ·)
+arrays so each grid step's block spans the array's two minor dims — the
+layout rule Mosaic enforces on blocks narrower than (8 × 128).
 
 The ``*_jnp`` variants are structure-exploiting gather/einsum forms of the
 same contractions (flops ∝ stored blocks, not m·n) — the off-TPU dispatch
@@ -41,7 +47,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
+from .autotune import HIGHEST, compiler_params
 
 Array = jax.Array
 
@@ -153,98 +159,164 @@ def effective_data(a: BlockELL) -> Array:
     return a.data
 
 
-def _load_block(a_ref, s_ref):
-    """One staged (bs × bs) block as f32: upcast sub-f32 storage on-chip
-    and apply the per-block dequant scale when the matrix is quantized.
-    The identity for exact-mode f32 data."""
-    a = a_ref[0]
+# SMEM words the scalar-prefetched column indices of one bsr_matmul call
+# may take (SMEM holds 1 MiB; the rest is left to the compiler).
+PREFETCH_WORDS = 64 * 1024
+
+
+def row_vectors(v: Array, nbr: int) -> Array:
+    """A per-block-row vector or index table as (nbr, 1, ·), so the one-row
+    block of each grid step spans the array's two minor dims."""
+    return v.reshape(nbr, 1, -1)
+
+
+def smem_rows(width: int) -> pl.BlockSpec:
+    """This grid step's row of a (nbr, 1, width) table, in SMEM."""
+    return pl.BlockSpec((1, 1, width), lambda i, *_: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def load_block(a_ref, s_ref, j) -> Array:
+    """Slot j of the staged (1, ell, bs, bs) block-row as f32: upcast
+    sub-f32 storage on-chip and apply the per-block dequant scale when the
+    matrix is quantized.  The identity for exact-mode f32 data."""
+    a = a_ref[0, j]
     if a.dtype != jnp.float32:
         a = a.astype(jnp.float32)
     if s_ref is not None:
-        a = a * s_ref[0, 0]
+        a = a * s_ref[0, 0, j]
     return a
 
 
-def _bsr_kernel(cols_ref, a_ref, *args, ell: int, quantized: bool):
-    del cols_ref   # consumed by the index_map gathers
-    if quantized:
-        s_ref, x_ref, o_ref, acc_ref = args
-    else:
-        (x_ref, o_ref, acc_ref), s_ref = args, None
+def _row_operands(a: BlockELL, nbr: int):
+    """(in_specs, operands) of the staged block-row: column list, blocks,
+    and the scales when quantized."""
+    specs = [smem_rows(a.ell),
+             pl.BlockSpec((1, a.ell, a.bs, a.bs), lambda i, *_: (i, 0, 0, 0))]
+    ops = [row_vectors(a.cols, nbr), a.data]
+    if a.scales is not None:
+        specs.append(smem_rows(a.ell))
+        ops.append(row_vectors(a.scales, nbr))
+    return specs, ops
 
-    @pl.when(pl.program_id(1) == 0)
+
+def _bsr_kernel(cols_ref, base_ref, a_ref, *args, quantized: bool):
+    del cols_ref, base_ref   # consumed by the index_map gathers
+    if quantized:
+        s_ref, x_ref, _, o_ref, acc_ref = args
+    else:
+        (x_ref, _, o_ref, acc_ref), s_ref = args, None
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(_load_block(a_ref, s_ref), x_ref[...],
-                            preferred_element_type=jnp.float32)
+    blk = a_ref[0, 0]
+    if blk.dtype != jnp.float32:
+        blk = blk.astype(jnp.float32)
+    if s_ref is not None:
+        blk = blk * s_ref[0, 0, j]
+    acc_ref[...] += jnp.dot(blk, x_ref[...],
+                            preferred_element_type=jnp.float32,
+                            precision=HIGHEST)
 
-    @pl.when(pl.program_id(1) == ell - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bsr_matmul(a: BlockELL, x: Array, *, interpret: bool = False) -> Array:
-    """y = A @ X for block-ELL A (m × n) and dense X (n × nx)."""
+    """y = A @ X for block-ELL A (m × n) and dense X (n × nx).
+
+    The X block of every slot is gathered by the index_map, so the column
+    indices are scalar-prefetched into SMEM.  To keep that prefetch within
+    PREFETCH_WORDS whatever the matrix size, the block-rows run in chunks of
+    `rows` per call, each call writing its rows of one aliased output; a
+    ragged last chunk repeats the final block-row, which rewrites the same
+    values."""
     m, n = a.shape
     assert x.shape[0] == n, (a.shape, x.shape)
     nx = x.shape[1]
     bs, ell = a.bs, a.ell
     nbr = m // bs
-    flat = a.data.reshape(nbr * ell, bs, bs)
-    cols = a.cols.reshape(-1)
     quantized = a.scales is not None
+    rows = max(1, min(nbr, PREFETCH_WORDS // ell))
+    chunks = -(-nbr // rows)
+    last = nbr - 1
 
-    in_specs = [
-        pl.BlockSpec((1, bs, bs), lambda i, j, cols: (i * ell + j, 0, 0)),
-    ]
-    operands = [cols, flat]
+    def blk_row(i, base):
+        return jnp.minimum(base[0] + i, last)
+
+    in_specs = [pl.BlockSpec((1, 1, bs, bs),
+                             lambda i, j, cols, base: (blk_row(i, base), j,
+                                                       0, 0))]
+    operands = [a.data]
     if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, 1), lambda i, j, cols: (i * ell + j, 0)))
-        operands.append(a.scales.reshape(nbr * ell, 1))
-    in_specs.append(
-        pl.BlockSpec((bs, nx), lambda i, j, cols: (cols[i * ell + j], 0)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, ell), lambda i, j, cols, base: (blk_row(i, base), 0, 0),
+            memory_space=pltpu.SMEM))
+        operands.append(row_vectors(a.scales, nbr))
+    in_specs.append(pl.BlockSpec(
+        (bs, nx), lambda i, j, cols, base: (cols[i * ell + j], 0)))
     operands.append(x)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nbr, ell),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bs, nx), lambda i, j, cols: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((bs, nx), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_bsr_kernel, ell=ell, quantized=quantized),
-        grid_spec=grid_spec,
+    alias = 2 + len(operands)            # the previous y, after the prefetch
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    call = pl.pallas_call(
+        functools.partial(_bsr_kernel, quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows, ell),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (bs, nx), lambda i, j, cols, base: (blk_row(i, base), 0)),
+            scratch_shapes=[pltpu.VMEM((bs, nx), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((m, nx), x.dtype),
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        input_output_aliases={alias: 0},
+        compiler_params=compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
         name="repro_bsr_matmul",
-    )(*operands)
+    )
+    # Column indices per chunk, the ragged tail padded with the last row's.
+    idx = jnp.minimum(jnp.arange(chunks * rows), last)
+    cols = a.cols[idx].reshape(chunks, rows * ell)
+
+    def chunk(k, y):
+        base = (k * rows)[None].astype(jnp.int32)
+        return call(cols[k], base, *operands, y)
+
+    return jax.lax.fori_loop(0, chunks, chunk,
+                             jnp.zeros((m, nx), x.dtype))
 
 
 def _bsr_spmv_kernel(cols_ref, a_ref, *args, ell: int, quantized: bool):
-    del cols_ref   # consumed by the index_map gathers
     if quantized:
-        s_ref, x_ref, o_ref, acc_ref = args
+        s_ref, x_ref, o_ref = args
     else:
-        (x_ref, o_ref, acc_ref), s_ref = args, None
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        (x_ref, o_ref), s_ref = args, None
+    bs = x_ref.shape[-1]
 
     # (1 × bs) · (bs × bs): the row-vector form of A_block @ x_block, so the
     # contraction still lands on the MXU.
-    acc_ref[...] += jnp.dot(x_ref[...], _load_block(a_ref, s_ref).T,
-                            preferred_element_type=jnp.float32)
+    def step(j, acc):
+        xj = x_ref[pl.ds(cols_ref[0, 0, j], 1), :]
+        return acc + jnp.dot(xj, load_block(a_ref, s_ref, j).T,
+                             preferred_element_type=jnp.float32,
+                             precision=HIGHEST)
 
-    @pl.when(pl.program_id(1) == ell - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+    z = jax.lax.fori_loop(0, ell, step, jnp.zeros((1, bs), jnp.float32))
+    o_ref[0] = z.astype(o_ref.dtype)
+
+
+def spmv_vmem(a: BlockELL) -> int:
+    """VMEM working set of bsr_matvec: the staged block-row and the
+    resident x, each double-buffered, tiles padded to (8 × 128)."""
+    bs, nbc = a.bs, a.shape[1] // a.bs
+    db = jnp.dtype(a.data.dtype).itemsize
+    bsl, bsp = -(-bs // 8) * 8, -(-bs // 128) * 128
+    return 2 * a.ell * bsl * bsp * db + 2 * nbc * bsp * 4 + 4 * 8 * bsp * 4
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -253,65 +325,48 @@ def bsr_matvec(a: BlockELL, x: Array, *, interpret: bool = False) -> Array:
     m, n = a.shape
     assert x.shape == (n,), (a.shape, x.shape)
     bs, ell = a.bs, a.ell
-    nbr = m // bs
-    flat = a.data.reshape(nbr * ell, bs, bs)
-    cols = a.cols.reshape(-1)
-    xb = x.reshape(n // bs, bs)
+    nbr, nbc = m // bs, n // bs
     quantized = a.scales is not None
-
-    in_specs = [
-        pl.BlockSpec((1, bs, bs), lambda i, j, cols: (i * ell + j, 0, 0)),
-    ]
-    operands = [cols, flat]
-    if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, 1), lambda i, j, cols: (i * ell + j, 0)))
-        operands.append(a.scales.reshape(nbr * ell, 1))
-    in_specs.append(
-        pl.BlockSpec((1, bs), lambda i, j, cols: (cols[i * ell + j], 0)))
-    operands.append(xb)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nbr, ell),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bs), lambda i, j, cols: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((1, bs), jnp.float32)],
-    )
+    in_specs, operands = _row_operands(a, nbr)
+    in_specs.append(pl.BlockSpec((nbc, bs), lambda i: (0, 0)))
+    operands.append(x.reshape(nbc, bs))
     out = pl.pallas_call(
         functools.partial(_bsr_spmv_kernel, ell=ell, quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nbr, bs), x.dtype),
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        grid=(nbr,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, bs), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nbr, 1, bs), x.dtype),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
         name="repro_bsr_matvec",
     )(*operands)
     return out.reshape(m)
 
 
-def _bsr_rmm_kernel(cols_ref, a_ref, *args, nbr: int, ell: int,
-                    quantized: bool):
+def _bsr_rmm_kernel(cols_ref, a_ref, *args, ell: int, quantized: bool):
+    # The f32 output block stays resident across the whole grid (its
+    # index_map is constant), so it is the accumulator itself.
     if quantized:
-        s_ref, x_ref, o_ref, acc_ref = args
+        s_ref, x_ref, acc_ref = args
     else:
-        (x_ref, o_ref, acc_ref), s_ref = args, None
-    i, j = pl.program_id(0), pl.program_id(1)
+        (x_ref, acc_ref), s_ref = args, None
 
-    @pl.when((i == 0) & (j == 0))
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    c = cols_ref[i * ell + j]
-    contrib = jnp.dot(_load_block(a_ref, s_ref).T, x_ref[...],
-                      preferred_element_type=jnp.float32)
-    cur = pl.load(acc_ref, (pl.ds(c, 1), slice(None), slice(None)))
-    pl.store(acc_ref, (pl.ds(c, 1), slice(None), slice(None)),
-             cur + contrib[None])
+    xi = x_ref[...]
+    if xi.dtype != jnp.float32:
+        xi = xi.astype(jnp.float32)
 
-    @pl.when((i == nbr - 1) & (j == ell - 1))
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+    def step(j, carry):
+        acc_ref[cols_ref[0, 0, j]] += jnp.dot(
+            load_block(a_ref, s_ref, j).T, xi,
+            preferred_element_type=jnp.float32,
+            precision=HIGHEST)
+        return carry
+
+    jax.lax.fori_loop(0, ell, step, 0)
 
 
 def _bsr_rmm_partials_kernel(a_ref, *args, quantized: bool):
@@ -319,16 +374,23 @@ def _bsr_rmm_partials_kernel(a_ref, *args, quantized: bool):
         s_ref, x_ref, o_ref = args
     else:
         (x_ref, o_ref), s_ref = args, None
-    o_ref[...] = jnp.dot(_load_block(a_ref, s_ref).T, x_ref[...],
-                         preferred_element_type=jnp.float32)[None]
+    blk = a_ref[0, 0]
+    if blk.dtype != jnp.float32:
+        blk = blk.astype(jnp.float32)
+    if s_ref is not None:
+        blk = blk * s_ref[0, 0, pl.program_id(1)]
+    o_ref[0, 0] = jnp.dot(blk.T, x_ref[...],
+                          preferred_element_type=jnp.float32,
+                          precision=HIGHEST)
 
 
-# Double-buffered streams + the resident accumulator + the full output copy
+# Double-buffered streams + the resident f32 output/accumulator block
 # must fit VMEM for the fused-scatter kernel to be legal.
-def _rmm_fused_vmem(nbc: int, bs: int, nx: int, itemsize: int) -> int:
-    return (2 * bs * bs * itemsize + 2 * bs * nx * itemsize   # A, X streams
-            + nbc * bs * nx * 4                               # f32 acc
-            + nbc * bs * nx * itemsize)                       # out copy
+def _rmm_fused_vmem(nbc: int, bs: int, nx: int, itemsize: int,
+                    ell: int = 1) -> int:
+    bsl, bsp = -(-bs // 8) * 8, -(-bs // 128) * 128
+    return (2 * ell * bsl * bsp * itemsize + 2 * bsl * nx * itemsize
+            + 2 * nbc * bsl * nx * 4)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -338,7 +400,7 @@ def bsr_rmatmul(a: BlockELL, x: Array, *, interpret: bool = False) -> Array:
     The transpose scatters: block (i, slot) contributes Aᵢⱼᵀ Xᵢ to output
     block-row j = cols[i, slot], and several grid steps can hit the same j.
     The scatter-add is fused into the kernel: the whole (nbc, bs, nx)
-    accumulator is VMEM-resident and each partial is added at its dynamic
+    accumulator is VMEM-resident and each partial is added at its
     block-column offset the moment the MXU produces it (the sequential grid
     makes the read-modify-write safe).  Padding slots carry zero data, so
     their contribution to block-row 0 vanishes.
@@ -346,7 +408,7 @@ def bsr_rmatmul(a: BlockELL, x: Array, *, interpret: bool = False) -> Array:
     The resident accumulator scales with n·nx, so when it cannot fit the
     VMEM budget (wide matrix × wide right-hand side — sparserow strips nx
     at 512, but n is unbounded) the kernel falls back to the emit-partials
-    form: one (nbr·ell, bs, nx) HBM buffer of per-block products plus an
+    form: one (nbr, ell, bs, nx) HBM buffer of per-block products plus an
     XLA segment_sum over block columns.
     """
     from . import autotune as _at
@@ -355,66 +417,46 @@ def bsr_rmatmul(a: BlockELL, x: Array, *, interpret: bool = False) -> Array:
     nx = x.shape[1]
     bs, ell = a.bs, a.ell
     nbr, nbc = m // bs, n // bs
-    flat = a.data.reshape(nbr * ell, bs, bs)
-    cols = a.cols.reshape(-1)
     quantized = a.scales is not None
-    flat_scales = a.scales.reshape(nbr * ell, 1) if quantized else None
 
-    if _rmm_fused_vmem(nbc, bs, nx, x.dtype.itemsize) > _at.VMEM_BUDGET:
-        in_specs = [
-            pl.BlockSpec((1, bs, bs), lambda i, j: (i * ell + j, 0, 0)),
-        ]
-        operands = [flat]
+    if _rmm_fused_vmem(nbc, bs, nx, x.dtype.itemsize, ell) \
+            > _at.VMEM_BUDGET:
+        in_specs = [pl.BlockSpec((1, 1, bs, bs), lambda i, j: (i, j, 0, 0))]
+        operands = [a.data]
         if quantized:
-            in_specs.append(
-                pl.BlockSpec((1, 1), lambda i, j: (i * ell + j, 0)))
-            operands.append(flat_scales)
+            in_specs.append(smem_rows(ell))
+            operands.append(row_vectors(a.scales, nbr))
         in_specs.append(pl.BlockSpec((bs, nx), lambda i, j: (i, 0)))
         operands.append(x)
         partial = pl.pallas_call(
             functools.partial(_bsr_rmm_partials_kernel, quantized=quantized),
             grid=(nbr, ell),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bs, nx),
-                                   lambda i, j: (i * ell + j, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((nbr * ell, bs, nx), jnp.float32),
-            compiler_params=compat.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
+            out_specs=pl.BlockSpec((1, 1, bs, nx),
+                                   lambda i, j: (i, j, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((nbr, ell, bs, nx), jnp.float32),
+            compiler_params=compiler_params("parallel", "parallel"),
             interpret=interpret,
             name="repro_bsr_rmatmul_partials",
         )(*operands)
-        out = jax.ops.segment_sum(partial, cols, num_segments=nbc)
+        out = jax.ops.segment_sum(partial.reshape(nbr * ell, bs, nx),
+                                  a.cols.reshape(-1), num_segments=nbc)
         return out.reshape(n, nx).astype(x.dtype)
 
-    in_specs = [
-        pl.BlockSpec((1, bs, bs), lambda i, j, cols: (i * ell + j, 0, 0)),
-    ]
-    operands = [cols, flat]
-    if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, 1), lambda i, j, cols: (i * ell + j, 0)))
-        operands.append(flat_scales)
-    in_specs.append(pl.BlockSpec((bs, nx), lambda i, j, cols: (i, 0)))
+    in_specs, operands = _row_operands(a, nbr)
+    in_specs.append(pl.BlockSpec((bs, nx), lambda i: (i, 0)))
     operands.append(x)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nbr, ell),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((nbc, bs, nx), lambda i, j, cols: (0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((nbc, bs, nx), jnp.float32)],
-    )
     out = pl.pallas_call(
-        functools.partial(_bsr_rmm_kernel, nbr=nbr, ell=ell,
-                          quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nbc, bs, nx), x.dtype),
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        functools.partial(_bsr_rmm_kernel, ell=ell, quantized=quantized),
+        grid=(nbr,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((nbc, bs, nx), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nbc, bs, nx), jnp.float32),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
         name="repro_bsr_rmatmul",
     )(*operands)
-    return out.reshape(n, nx)
+    return out.reshape(n, nx).astype(x.dtype)
 
 
 # -- structure-exploiting jnp forms (off-TPU dispatch targets) ----------------

@@ -25,7 +25,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
 
 Array = jax.Array
 
@@ -112,7 +111,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, scale: float | None = None,
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="repro_flash_attention",

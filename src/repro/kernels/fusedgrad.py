@@ -27,8 +27,10 @@ Two layouts share the row-local loss math:
   * ``fused_grad``     — dense tall-skinny row shards (the RowMatrix path);
   * ``fused_grad_bsr`` — BlockELL shards (kernels/bsr.py layout): the whole
     block-row's stored blocks are staged per grid step, z accumulates over
-    the ell slots, and the transpose contributions scatter-add into a
-    resident (nbc × bs) accumulator — each stored block is read once.
+    the ell slots against a VMEM-resident x, and the transpose
+    contributions scatter-add into a resident (nbc × bs) accumulator at
+    the block-column read from the row's SMEM column list — each stored
+    block is read once.
 
 The ``*_jnp`` forms are the structure-exploiting off-TPU dispatch targets
 (kernels/ops.py); the densifying oracle lives in kernels/ref.py.
@@ -42,8 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
-from .bsr import BlockELL, effective_data
+from .autotune import HIGHEST, compiler_params
+from .bsr import BlockELL, effective_data, load_block, row_vectors, smem_rows
 
 Array = jax.Array
 
@@ -112,11 +114,13 @@ def _fused_grad_kernel(a_ref, x_ref, t_ref, w_ref, f_ref, g_ref, z_ref,
         blk = blk.astype(jnp.float32)
     # Row-vector matmuls keep both contractions on the MXU: z = x Aᵀ and
     # g += r A are (1 × bm)·(bm × n) products over the block already in VMEM.
-    z = jnp.dot(x_ref[...], blk.T, preferred_element_type=jnp.float32)
+    z = jnp.dot(x_ref[...], blk.T, preferred_element_type=jnp.float32,
+                                   precision=HIGHEST)
     fpart, r = row_loss_grad(z, t_ref[...], w_ref[...], loss, param)
     z_ref[...] = z
     g_acc[...] += jnp.dot(r.astype(blk.dtype), blk,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=HIGHEST)
     f_acc[0, 0] += fpart
 
     @pl.when(pl.program_id(0) == m_steps - 1)
@@ -162,8 +166,7 @@ def fused_grad(a: Array, x: Array, t: Array, w: Array, *, loss: str,
         ],
         scratch_shapes=[pltpu.VMEM((1, n), jnp.float32),
                         pltpu.SMEM((1, 1), jnp.float32)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
         name="repro_fused_grad",
     )(a, x, t, w)
@@ -171,64 +174,59 @@ def fused_grad(a: Array, x: Array, t: Array, w: Array, *, loss: str,
 
 # -- BlockELL (BSR) kernel ----------------------------------------------------
 
-def fused_grad_bsr_vmem(a: BlockELL) -> int:
-    """Resident VMEM working-set estimate for the BSR fused kernel: the
-    staged block-row (double-buffered), the full x copy, the f32 gradient
-    accumulator + output copy, and the t/w/z vector strips.  ops dispatch
-    falls back to a two-pass BSR composition when this exceeds the budget
-    (mirroring bsr_rmatmul's own fallback)."""
-    bs, ell = a.bs, a.ell
-    nbc = a.shape[1] // bs
-    db = jnp.dtype(a.data.dtype).itemsize
-    return (2 * ell * bs * bs * db        # block-row stream, double-buffered
-            + nbc * bs * db               # resident x
-            + nbc * bs * 4 + nbc * bs * 4  # g accumulator + g out (f32)
-            + 6 * bs * 4)                 # t, w, z (1 × bs) strips
+def fused_grad_bsr_vmem(bs: int, ell: int, n: int, dtype) -> int:
+    """Resident VMEM working-set estimate for the BSR fused kernel over an
+    n-wide BlockELL of `ell` (bs × bs) blocks per block-row, every
+    pipelined block counted twice: the staged block-row, x, the f32
+    gradient accumulator and output, and the t/w/z (1 × bs) strips.  Tiles
+    pad to (8 sublanes × 128 lanes).  Past the budget the planner's "grad"
+    decision picks the two passes, and ops dispatch composes them (mirroring
+    bsr_rmatmul's own fallback)."""
+    nbc = -(-n // bs)
+    db = jnp.dtype(dtype).itemsize
+    bsl, bsp = -(-bs // 8) * 8, -(-bs // 128) * 128
+    return (2 * ell * bsl * bsp * db       # block-row stream
+            + 2 * nbc * bsp * db           # resident x
+            + 3 * nbc * bsp * 4            # g accumulator + g out (f32)
+            + 6 * 8 * bsp * 4)             # t, w, z (1 × bs) strips
 
 
 def _fused_grad_bsr_kernel(cols_ref, a_ref, x_ref, t_ref, w_ref,
-                           f_ref, g_ref, z_ref, g_acc, f_acc, *,
-                           nbr: int, ell: int, loss: str, param: float):
+                           f_ref, g_ref, z_ref, g_acc, f_acc, *, ell: int,
+                           loss: str, param: float):
     i = pl.program_id(0)
+    bs = x_ref.shape[-1]
 
     @pl.when(i == 0)
     def _init():
         g_acc[...] = jnp.zeros_like(g_acc)
         f_acc[0, 0] = jnp.float32(0.0)
 
-    blocks = a_ref[0]                                    # (ell, bs, bs)
-    if blocks.dtype != jnp.float32:
-        blocks = blocks.astype(jnp.float32)    # sub-f32 storage upcast
-    bs = blocks.shape[-1]
-    xall = x_ref[...]                                    # (nbc, bs)
-
     # z for the whole block-row: accumulate over the ell stored blocks while
     # they are staged in VMEM (padding slots are zero, so col 0 is harmless).
     def zstep(j, zacc):
-        c = cols_ref[i * ell + j]
-        xj = jax.lax.dynamic_index_in_dim(xall, c, 0, keepdims=True)
-        bj = jax.lax.dynamic_index_in_dim(blocks, j, 0, keepdims=False)
-        return zacc + jnp.dot(xj, bj.T, preferred_element_type=jnp.float32)
+        xj = x_ref[pl.ds(cols_ref[0, 0, j], 1), :]
+        return zacc + jnp.dot(xj, load_block(a_ref, None, j).T,
+                              preferred_element_type=jnp.float32,
+                              precision=HIGHEST)
 
     z = jax.lax.fori_loop(0, ell, zstep, jnp.zeros((1, bs), jnp.float32))
-    fpart, r = row_loss_grad(z, t_ref[...], w_ref[...], loss, param)
-    z_ref[...] = z
+    fpart, r = row_loss_grad(z, t_ref[0], w_ref[0], loss, param)
+    z_ref[0] = z
     f_acc[0, 0] += fpart
 
     # Second sweep over the SAME staged blocks (no HBM re-read): scatter-add
     # each Aᵢⱼᵀ r into the resident block-column accumulator.
     def gstep(j, carry):
-        c = cols_ref[i * ell + j]
-        bj = jax.lax.dynamic_index_in_dim(blocks, j, 0, keepdims=False)
-        contrib = jnp.dot(r.astype(bj.dtype), bj,
-                          preferred_element_type=jnp.float32)
-        cur = pl.load(g_acc, (pl.ds(c, 1), slice(None)))
-        pl.store(g_acc, (pl.ds(c, 1), slice(None)), cur + contrib)
+        c = cols_ref[0, 0, j]
+        g_acc[pl.ds(c, 1), :] += jnp.dot(r, load_block(a_ref, None, j),
+                                         preferred_element_type=jnp.float32,
+                                         precision=HIGHEST)
         return carry
 
     jax.lax.fori_loop(0, ell, gstep, 0)
 
-    @pl.when(i == nbr - 1)
+    @pl.when(i == pl.num_programs(0) - 1)
     def _flush():
         g_ref[...] = g_acc[...]
         f_ref[0, 0] = f_acc[0, 0]
@@ -246,41 +244,33 @@ def fused_grad_bsr(a: BlockELL, x: Array, t: Array, w: Array, *, loss: str,
         (a.shape, x.shape, t.shape, w.shape)
     bs, ell = a.bs, a.ell
     nbr, nbc = m // bs, n // bs
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nbr,),
-        in_specs=[
-            pl.BlockSpec((1, ell, bs, bs), lambda i, cols: (i, 0, 0, 0)),
-            pl.BlockSpec((nbc, bs), lambda i, cols: (0, 0)),
-            pl.BlockSpec((1, bs), lambda i, cols: (0, i)),
-            pl.BlockSpec((1, bs), lambda i, cols: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, cols: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((nbc, bs), lambda i, cols: (0, 0)),
-            pl.BlockSpec((1, bs), lambda i, cols: (0, i)),
-        ],
-        scratch_shapes=[pltpu.VMEM((nbc, bs), jnp.float32),
-                        pltpu.SMEM((1, 1), jnp.float32)],
-    )
+    assert a.scales is None, "quantized shards take the two-pass form"
+    row = pl.BlockSpec((1, 1, bs), lambda i: (i, 0, 0))
     f, g, z = pl.pallas_call(
-        functools.partial(_fused_grad_bsr_kernel, nbr=nbr, ell=ell,
-                          loss=loss, param=float(param)),
-        grid_spec=grid_spec,
+        functools.partial(_fused_grad_bsr_kernel, ell=ell, loss=loss,
+                          param=float(param)),
+        grid=(nbr,),
+        in_specs=[smem_rows(ell),
+                  pl.BlockSpec((1, ell, bs, bs), lambda i: (i, 0, 0, 0)),
+                  pl.BlockSpec((nbc, bs), lambda i: (0, 0)), row, row],
+        out_specs=[
+            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((nbc, bs), lambda i: (0, 0)),
+            row,
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
             jax.ShapeDtypeStruct((nbc, bs), jnp.float32),
-            jax.ShapeDtypeStruct((1, m), jnp.float32),
+            jax.ShapeDtypeStruct((nbr, 1, bs), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        scratch_shapes=[pltpu.VMEM((nbc, bs), jnp.float32),
+                        pltpu.SMEM((1, 1), jnp.float32)],
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
         name="repro_fused_grad_bsr",
-    )(a.cols.reshape(-1), a.data.reshape(nbr, ell, bs, bs),
-      x.reshape(nbc, bs), t.reshape(1, m), w.reshape(1, m))
-    return f[0, 0], g.reshape(n), z[0]
+    )(row_vectors(a.cols, nbr), a.data, x.reshape(nbc, bs),
+      row_vectors(t, nbr), row_vectors(w, nbr))
+    return f[0, 0], g.reshape(n), z.reshape(m)
 
 
 # -- multi-RHS (request-batched) dense kernel ---------------------------------
@@ -299,11 +289,13 @@ def _fused_grad_multi_kernel(a_ref, x_ref, t_ref, w_ref, f_ref, g_ref, z_ref,
     x = x_ref[...]                                       # (kp, n)
     # One block read serves every request: z = X Aᵀ is a (kp × n)·(n × bm)
     # product over the block already in VMEM — the whole point of grouping.
-    z = jnp.dot(x, blk.T, preferred_element_type=jnp.float32)   # (kp, bm)
+    z = jnp.dot(x, blk.T, preferred_element_type=jnp.float32,
+                          precision=HIGHEST)   # (kp, bm)
     le, r = row_loss_elem(z, t_ref[...], w_ref[...], loss, param)
     z_ref[...] = z
     g_acc[...] += jnp.dot(r.astype(blk.dtype), blk,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=HIGHEST)
     # Per-request loss: fold the lane-aligned bm axis down to one 128-lane
     # strip (bm % 128 == 0 by layout contract); the host sums the strip.
     kp, bm = le.shape
@@ -357,8 +349,7 @@ def fused_grad_multi(a: Array, x: Array, t: Array, w: Array, *, loss: str,
         ],
         scratch_shapes=[pltpu.VMEM((kp, n), jnp.float32),
                         pltpu.VMEM((kp, 128), jnp.float32)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
         name="repro_fused_grad_multi",
     )(a, x, t, w)
@@ -369,62 +360,55 @@ def fused_grad_multi(a: Array, x: Array, t: Array, w: Array, *, loss: str,
 def fused_grad_bsr_multi_vmem(a: BlockELL, kp: int) -> int:
     """Resident VMEM working-set estimate for the multi-RHS BSR fused
     kernel: the per-request copies of x, the gradient accumulator, and the
-    t/w/z strips all scale with kp; the staged block-row does not."""
+    t/w/z strips all scale with kp; the staged block-row does not.  Every
+    pipelined block counted twice, tiles padded to (8 × 128)."""
     bs, ell = a.bs, a.ell
     nbc = a.shape[1] // bs
     db = jnp.dtype(a.data.dtype).itemsize
-    return (2 * ell * bs * bs * db          # block-row stream, double-buffered
-            + nbc * kp * bs * db            # resident x (nbc × kp × bs)
-            + 2 * nbc * kp * bs * 4         # g accumulator + g out (f32)
-            + kp * bs * 4                   # f accumulator strip
-            + 6 * kp * bs * 4)              # t, w, z (kp × bs) strips
+    bsl, bsp = -(-bs // 8) * 8, -(-bs // 128) * 128
+    return (2 * ell * bsl * bsp * db        # block-row stream
+            + 2 * nbc * kp * bsp * db       # resident x (nbc × kp × bs)
+            + 3 * nbc * kp * bsp * 4        # g accumulator + g out (f32)
+            + 3 * kp * bsp * 4              # f accumulator + f out
+            + 6 * kp * bsp * 4)             # t, w, z (kp × bs) strips
 
 
 def _fused_grad_bsr_multi_kernel(cols_ref, a_ref, x_ref, t_ref, w_ref,
                                  f_ref, g_ref, z_ref, g_acc, f_acc, *,
-                                 nbr: int, ell: int, loss: str, param: float):
+                                 ell: int, loss: str, param: float):
     i = pl.program_id(0)
+    kp, bs = x_ref.shape[1], x_ref.shape[2]
 
     @pl.when(i == 0)
     def _init():
         g_acc[...] = jnp.zeros_like(g_acc)
         f_acc[...] = jnp.zeros_like(f_acc)
 
-    blocks = a_ref[0]                                    # (ell, bs, bs)
-    if blocks.dtype != jnp.float32:
-        blocks = blocks.astype(jnp.float32)    # sub-f32 storage upcast
-    bs = blocks.shape[-1]
-    kp = x_ref.shape[1]
-    xall = x_ref[...]                                    # (nbc, kp, bs)
-
     # z for the whole block-row, all requests at once: each staged block is
     # contracted against the (kp × bs) slab of x for its block-column.
     def zstep(j, zacc):
-        c = cols_ref[i * ell + j]
-        xj = jax.lax.dynamic_index_in_dim(xall, c, 0, keepdims=False)
-        bj = jax.lax.dynamic_index_in_dim(blocks, j, 0, keepdims=False)
-        return zacc + jnp.dot(xj, bj.T, preferred_element_type=jnp.float32)
+        xj = x_ref[cols_ref[0, 0, j]]                     # (kp, bs)
+        return zacc + jnp.dot(xj, load_block(a_ref, None, j).T,
+                              preferred_element_type=jnp.float32,
+                              precision=HIGHEST)
 
     z = jax.lax.fori_loop(0, ell, zstep, jnp.zeros((kp, bs), jnp.float32))
-    le, r = row_loss_elem(z, t_ref[...], w_ref[...], loss, param)
-    z_ref[...] = z
+    le, r = row_loss_elem(z, t_ref[0], w_ref[0], loss, param)
+    z_ref[0] = z
     f_acc[...] += le                                     # (kp, bs), summed on host
 
     # Second sweep over the SAME staged blocks (no HBM re-read): scatter-add
     # each (kp × bs) Aᵢⱼᵀ r slab into the resident block-column accumulator.
     def gstep(j, carry):
-        c = cols_ref[i * ell + j]
-        bj = jax.lax.dynamic_index_in_dim(blocks, j, 0, keepdims=False)
-        contrib = jnp.dot(r.astype(bj.dtype), bj,
-                          preferred_element_type=jnp.float32)
-        cur = pl.load(g_acc, (pl.ds(c, 1), slice(None), slice(None)))
-        pl.store(g_acc, (pl.ds(c, 1), slice(None), slice(None)),
-                 cur + contrib[None])
+        c = cols_ref[0, 0, j]
+        g_acc[c] += jnp.dot(r, load_block(a_ref, None, j),
+                            preferred_element_type=jnp.float32,
+                            precision=HIGHEST)
         return carry
 
     jax.lax.fori_loop(0, ell, gstep, 0)
 
-    @pl.when(i == nbr - 1)
+    @pl.when(i == pl.num_programs(0) - 1)
     def _flush():
         g_ref[...] = g_acc[...]
         f_ref[...] = f_acc[...]
@@ -446,41 +430,39 @@ def fused_grad_bsr_multi(a: BlockELL, x: Array, t: Array, w: Array, *,
         (a.shape, x.shape, t.shape, w.shape)
     bs, ell = a.bs, a.ell
     nbr, nbc = m // bs, n // bs
+    assert a.scales is None, "quantized shards take the two-pass form"
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nbr,),
-        in_specs=[
-            pl.BlockSpec((1, ell, bs, bs), lambda i, cols: (i, 0, 0, 0)),
-            pl.BlockSpec((nbc, kp, bs), lambda i, cols: (0, 0, 0)),
-            pl.BlockSpec((kp, bs), lambda i, cols: (0, i)),
-            pl.BlockSpec((kp, bs), lambda i, cols: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((kp, bs), lambda i, cols: (0, 0)),
-            pl.BlockSpec((nbc, kp, bs), lambda i, cols: (0, 0, 0)),
-            pl.BlockSpec((kp, bs), lambda i, cols: (0, i)),
-        ],
-        scratch_shapes=[pltpu.VMEM((nbc, kp, bs), jnp.float32),
-                        pltpu.VMEM((kp, bs), jnp.float32)],
-    )
+    def rows(v):            # (kp, m) -> (nbr, kp, bs): one block-row a step
+        return v.reshape(kp, nbr, bs).transpose(1, 0, 2)
+
+    row = pl.BlockSpec((1, kp, bs), lambda i: (i, 0, 0))
     f, g, z = pl.pallas_call(
-        functools.partial(_fused_grad_bsr_multi_kernel, nbr=nbr, ell=ell,
+        functools.partial(_fused_grad_bsr_multi_kernel, ell=ell,
                           loss=loss, param=float(param)),
-        grid_spec=grid_spec,
+        grid=(nbr,),
+        in_specs=[smem_rows(ell),
+                  pl.BlockSpec((1, ell, bs, bs), lambda i: (i, 0, 0, 0)),
+                  pl.BlockSpec((nbc, kp, bs), lambda i: (0, 0, 0)),
+                  row, row],
+        out_specs=[
+            pl.BlockSpec((kp, bs), lambda i: (0, 0)),
+            pl.BlockSpec((nbc, kp, bs), lambda i: (0, 0, 0)),
+            row,
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((kp, bs), jnp.float32),
             jax.ShapeDtypeStruct((nbc, kp, bs), jnp.float32),
-            jax.ShapeDtypeStruct((kp, m), jnp.float32),
+            jax.ShapeDtypeStruct((nbr, kp, bs), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        scratch_shapes=[pltpu.VMEM((nbc, kp, bs), jnp.float32),
+                        pltpu.VMEM((kp, bs), jnp.float32)],
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
         name="repro_fused_grad_bsr_multi",
-    )(a.cols.reshape(-1), a.data.reshape(nbr, ell, bs, bs),
-      x.reshape(kp, nbc, bs).transpose(1, 0, 2),
-      t.reshape(kp, m), w.reshape(kp, m))
-    return f.sum(axis=1), g.transpose(1, 0, 2).reshape(kp, n), z
+    )(row_vectors(a.cols, nbr), a.data,
+      x.reshape(kp, nbc, bs).transpose(1, 0, 2), rows(t), rows(w))
+    return (f.sum(axis=1), g.transpose(1, 0, 2).reshape(kp, n),
+            z.transpose(1, 0, 2).reshape(kp, m))
 
 
 # -- structured jnp forms (off-TPU dispatch targets) --------------------------

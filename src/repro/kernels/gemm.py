@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
+from .autotune import HIGHEST, compiler_params
 
 Array = jax.Array
 
@@ -41,7 +41,8 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
         a = a.astype(jnp.float32)
     if b.dtype != jnp.float32:
         b = b.astype(jnp.float32)
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32,
+                                  precision=HIGHEST)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -72,8 +73,7 @@ def gemm(a: Array, b: Array, *, bm: int, bn: int, bk: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
         name="repro_gemm",
     )(a, b)

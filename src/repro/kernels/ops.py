@@ -63,17 +63,20 @@ def gemm(a: Array, b: Array, *, bm: int | None = None, bn: int | None = None,
     return out[:m, :n]
 
 
-def tsgram(a: Array, *, bm: int | None = None, tune: str = "auto",
-           out_dtype=None, force_pallas: bool = False) -> Array:
-    """G = AᵀA for tall-skinny A (n padded to lanes internally)."""
+def tsgram(a: Array, *, bm: int | None = None, bn: int | None = None,
+           tune: str = "auto", out_dtype=None,
+           force_pallas: bool = False) -> Array:
+    """G = AᵀA for tall-skinny A (n padded to lanes internally).  Grams too
+    wide for one VMEM-resident accumulator run in (bn × bn) output tiles."""
     if not (_on_tpu() or force_pallas):
         return _ref.tsgram_ref(a, out_dtype)
     m, n = a.shape
-    cfg = _tune.resolve("tsgram", {"m": m, "n": n}, a.dtype, {"bm": bm},
-                        tune=tune)
+    cfg = _tune.resolve("tsgram", {"m": m, "n": n}, a.dtype,
+                        {"bm": bm, "bn": bn}, tune=tune)
     bm_ = min(cfg["bm"], _rup(m, 8))
+    bn_ = _tune._tsgram_bn(cfg, {"n": n})
     ap = _pad_to(_pad_to(a, 0, bm_), 1, 128)
-    out = _tsgram.tsgram(ap, bm=bm_, out_dtype=out_dtype,
+    out = _tsgram.tsgram(ap, bm=bm_, bn=bn_, out_dtype=out_dtype,
                          interpret=not _on_tpu())
     return out[:n, :n]
 
@@ -117,6 +120,9 @@ def bsr_matvec(a: "_bsr.BlockELL", x: Array, *,
     """y = A @ x for block-sparse A and a vector x (n,)."""
     if not (_on_tpu() or force_pallas):
         return _bsr.bsr_matvec_jnp(a, x)
+    if _bsr.spmv_vmem(a) > _tune.VMEM_BUDGET:
+        # x too wide to stay VMEM-resident: gather its blocks per slot.
+        return bsr_matmul(a, x[:, None], force_pallas=force_pallas)[:, 0]
     return _bsr.bsr_matvec(a, x, interpret=not _on_tpu())
 
 
@@ -179,7 +185,8 @@ def fused_grad_bsr(a: "_bsr.BlockELL", x: Array, target: Array,
     # int8-quantized shards compose the scale-aware SpMV/rmatmul kernels
     # (two reads of the stored blocks — still half the bytes of one f32
     # read); exact-mode shards keep the single-read fused kernel.
-    if a.scales is not None or _fg.fused_grad_bsr_vmem(a) > _tune.VMEM_BUDGET:
+    if a.scales is not None or _fg.fused_grad_bsr_vmem(
+            a.bs, a.ell, a.shape[1], a.data.dtype) > _tune.VMEM_BUDGET:
         z = bsr_matvec(a, x, force_pallas=force_pallas)
         f, r = _fg.row_loss_grad(z, target, weights, loss, param)
         g = bsr_rmatmul(a, r.astype(x.dtype)[:, None],
@@ -206,8 +213,8 @@ def fused_grad_multi(a: Array, x: Array, target: Array, weights: Array, *,
         f, g, z = _fg.fused_grad_multi_jnp(a, x, target, weights, loss=loss,
                                            param=param)
         return f, g.astype(x.dtype), z
-    cfg = _tune.resolve("fusedgrad", {"m": m, "n": n}, a.dtype, {"bm": bm},
-                        tune=tune)
+    cfg = _tune.resolve("fusedgrad", {"m": m, "n": n, "k": _rup(k, 8)},
+                        a.dtype, {"bm": bm}, tune=tune)
     bm_ = min(cfg["bm"], _rup(m, 128))
     ap = _pad_to(_pad_to(a, 0, bm_), 1, 128)
     # Pad the request axis to the f32 sublane multiple (8) and the feature

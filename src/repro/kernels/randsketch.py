@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
+from .autotune import HIGHEST, compiler_params
 
 Array = jax.Array
 
@@ -46,7 +46,8 @@ def _randsketch_kernel(a_ref, q_ref, o_ref, acc_ref, *, m_steps: int):
         a = a.astype(jnp.float32)
     if q.dtype != jnp.float32:
         q = q.astype(jnp.float32)
-    acc_ref[...] += jnp.dot(a.T, q, preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(a.T, q, preferred_element_type=jnp.float32,
+                                    precision=HIGHEST)
 
     @pl.when(pl.program_id(1) == m_steps - 1)
     def _flush():
@@ -76,8 +77,7 @@ def randsketch(a: Array, q: Array, *, bm: int, bn: int,
         out_specs=pl.BlockSpec((bn, r), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, r), out_dtype),
         scratch_shapes=[pltpu.VMEM((bn, r), jnp.float32)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
         name="repro_randsketch",
     )(a, q)

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
 
 Array = jax.Array
 
@@ -83,7 +82,7 @@ def selective_scan(x: Array, dt: Array, A: Array, B: Array, C: Array,
         out_specs=pl.BlockSpec((1, q, bd), lambda b, j, s: (b, s, j)),
         out_shape=jax.ShapeDtypeStruct((Bt, S, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="repro_selective_scan",

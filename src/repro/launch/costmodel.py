@@ -23,7 +23,7 @@ import dataclasses
 
 import numpy as np
 
-from repro import compat, configs
+from repro import configs
 from repro.launch import roofline as RL
 
 
@@ -97,7 +97,7 @@ def measure(arch: str, shape: str, mesh, make_plan_fn, plan_kw: dict,
                             **{**plan_kw, "microbatches": 1,
                                "overrides": ov})
         compiled = plan.lower().compile()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         flops.append(float(cost.get("flops", 0.0)))
         hbm.append(float(cost.get("bytes accessed", 0.0)))
         coll.append(RL.parse_collectives(compiled.as_text()))

@@ -308,7 +308,7 @@ V5E = MachineModel(
     hbm_bw=819e9,                        # bytes/s per chip
     step_overhead_s=2e-7,                # per-grid-step issue cost
     link_bw=50e9,                        # bytes/s per ICI link
-    vmem_bytes=16 * 2**20,
+    vmem_bytes=128 * 2**20,              # physical VMEM per TensorCore
     link_latency_s=1e-6)                 # per-ICI-hop collective latency
 
 CPU = MachineModel(
@@ -320,11 +320,33 @@ CPU = MachineModel(
     vmem_bytes=16 * 2**20,               # keeps tilings TPU-shaped
     link_latency_s=2e-6)                 # shared-memory "hop" (host psum)
 
-_BUILTIN = {"tpu": V5E, "cpu": CPU}
+# TPU models by the device_kind JAX reports: a chip with no entry is an
+# error, never a default.
+TPU_BY_KIND = {"TPU v5 lite": V5E}
+
+
+def tpu_model(device_kind: str) -> MachineModel:
+    """The built-in model of the TPU JAX names `device_kind`."""
+    try:
+        return TPU_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(f"no machine model for TPU device_kind "
+                         f"{device_kind!r} (known: {sorted(TPU_BY_KIND)})"
+                         ) from None
+
+
+def _attached_tpu() -> MachineModel | None:
+    """The model of the TPU this process runs on, None off TPU."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return None
+    return tpu_model(jax.devices()[0].device_kind)
 
 
 def builtin(backend: str) -> MachineModel:
-    return _BUILTIN.get(backend, CPU)
+    if backend == "tpu":
+        return _attached_tpu() or V5E
+    return CPU
 
 
 # -- calibration cache (next to the autotune config cache) --------------------
@@ -376,14 +398,16 @@ def save_calibration(backend: str, model: MachineModel,
 def for_backend(backend: str | None = None, *,
                 prefer_calibrated: bool = True) -> MachineModel:
     """The machine model every dispatch decision prices against: the
-    calibrated model for this backend when one has been recorded, else the
-    v5e reference instance (see module docstring for why the reference —
-    not the CPU instance — is the uncalibrated default everywhere)."""
+    calibrated model for this backend when one has been recorded; on a TPU
+    the built-in model of its device_kind (an unknown chip raises); else
+    the v5e reference instance (see module docstring for why the reference
+    — not the CPU instance — is the uncalibrated default off TPU)."""
     if backend is None:
         import jax
         backend = jax.default_backend()
+    attached = _attached_tpu() if backend == "tpu" else None
     if prefer_calibrated:
         entry = _calibrations(calibration_path()).get(backend)
         if entry is not None:
             return MachineModel.from_dict(entry)
-    return V5E
+    return attached or V5E

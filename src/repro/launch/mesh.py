@@ -11,21 +11,24 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+from repro.core.distmat.types import make_mesh
+
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (host) devices exist — tests/drivers."""
+    """A (data, model) mesh over the first data·model devices; raises when
+    fewer exist, so a run sized for a mesh never silently runs on less."""
     n = len(jax.devices())
     if data * model > n:
-        data, model = n, 1
-    return compat.make_mesh((data, model), ("data", "model"))
+        raise ValueError(f"mesh ({data}, {model}) needs {data * model} "
+                         f"devices, {n} available")
+    return make_mesh((data, model), ("data", "model"))
 
 
 def axis_sizes(mesh, axes=None) -> tuple[int, ...]:

@@ -30,7 +30,9 @@ Supported ops:
                            gradient (one A read vs two); with context
                            {"axes": mesh axis sizes} the psum of (f, g) is
                            priced end-to-end and an overlapped chunk count
-                           is chosen (blocks["chunks"], 1 = eager)
+                           is chosen (blocks["chunks"], 1 = eager).  A
+                           BlockELL shard passes {m, n, bs, ell} + context
+                           {"kind": "sparse"} (dtype int8 = quantized)
   "bsr_bs"                 {m, n, nx} + context {"ell_by_bs": {bs: ell}}
                            block-size selection on actual ELL widths
   "svd"                    {m, n, k} + context {"kind": "row"|"sparse"|
@@ -62,6 +64,7 @@ time); ``kernels.autotune.reset()`` clears every layer at once.
 from __future__ import annotations
 
 import functools
+import math
 import json
 from dataclasses import dataclass, field
 from typing import Mapping
@@ -455,10 +458,15 @@ def _decide_grad(d, dtype_name, machine, ctx, kw) -> ExecutionPlan:
     the next segment's compute (an extra read of A buys comm hiding —
     RowMatrix.fused_grad implements blocks["chunks"])."""
     import jax.numpy as jnp
+    if ctx.get("kind") == "sparse":
+        return _decide_grad_sparse(d, dtype_name, machine, ctx, kw)
     m, n = d["m"], d["n"]
     db = jnp.dtype(dtype_name).itemsize
-    fused_s, fused_blocks = at.rank("fusedgrad", {"m": m, "n": n},
-                                    dtype_name, machine=machine)[0]
+    ranked = at.rank("fusedgrad", {"m": m, "n": n}, dtype_name,
+                     machine=machine)
+    # No row block of the dense kernel fits VMEM at this width: the fused
+    # kernel is out, the two streaming passes are not.
+    fused_s, fused_blocks = ranked[0] if ranked else (math.inf, {})
     mp = at._rup(m, at.sublane(dtype_name))
     np_ = at._rup(n, LANE)
     bm = min(512, mp)
@@ -493,10 +501,9 @@ def _decide_grad(d, dtype_name, machine, ctx, kw) -> ExecutionPlan:
     # {"wire": "int8"} prices the compressed-collective wire format.
     wire = ctx.get("wire")
     coll = _psum_cost(machine, n + 1.0, axes, dtype_name, wire)
-    fused_terms = at.cost_terms("fusedgrad", fused_blocks,
-                                {"m": m, "n": n}, dtype_name)
-    cands = [("fused", 1, fused_s + coll["comm_s"],
-              _with_comm(fused_terms, coll))]
+    cands = [("fused", 1, fused_s + coll["comm_s"], _with_comm(
+        at.cost_terms("fusedgrad", fused_blocks, {"m": m, "n": n},
+                      dtype_name), coll))] if ranked else []
     pre = machine.time(pass_terms, dtype_name)
     for c in _chunk_counts(n):
         if c == 1:
@@ -536,6 +543,38 @@ def _decide_grad(d, dtype_name, machine, ctx, kw) -> ExecutionPlan:
         alternatives=tuple(sorted(((lb, s) for lb, _, s, _ in cands),
                                   key=lambda t: t[1])),
         notes=tuple(notes), terms=_terms_dict(chosen_terms), **kw)
+
+
+def _decide_grad_sparse(d, dtype_name, machine, ctx, kw) -> ExecutionPlan:
+    """Fused single-read BSR gradient vs SpMV + transpose-multiply for an
+    (m × n) BlockELL shard of `ell` (bs × bs) blocks per block-row.  Both
+    sides stream the same padded blocks, so the fused kernel (one read
+    against two) wins wherever it runs.  It does not run on int8-quantized
+    blocks (no scale-aware fused kernel) or once its n-wide resident x and
+    gradient accumulator outgrow VMEM; ops.fused_grad_bsr composes the two
+    passes there, and the plan says so."""
+    from repro.kernels import fusedgrad as _fg
+    m, n, bs, ell = d["m"], d["n"], d["bs"], d["ell"]
+    one = at.cost_terms("bsr", {"bs": bs},
+                        {"m": m, "n": n, "nx": 1, "ell": ell}, dtype_name)
+    pass_s = machine.time(one, dtype_name)
+    fits = dtype_name != "int8" and _fg.fused_grad_bsr_vmem(
+        bs, ell, n, dtype_name) <= at.VMEM_BUDGET
+    cands = [("fused", 1, pass_s)] if fits else []
+    cands.append(("unfused", 2, 2.0 * pass_s))
+    label, reads, cost_s = min(cands, key=lambda t: t[2])
+    terms = CostTerms(flops=reads * one.flops, hbm_bytes=reads * one.hbm_bytes,
+                      steps=reads * one.steps, mxu_util=one.mxu_util)
+    return ExecutionPlan(
+        op="grad", choice=label,
+        blocks={"bs": bs} if label == "fused" else {},
+        cost_s=cost_s, breakdown=machine.breakdown(terms, dtype_name),
+        alternatives=tuple(sorted(((lb, s) for lb, _, s in cands),
+                                  key=lambda t: t[1])),
+        notes=("fused = 1 read of the stored blocks; unfused = 2" + (
+            "" if fits else "; the fused BSR kernel cannot run here "
+            "(int8 blocks or VMEM)"),),
+        terms=_terms_dict(terms), **kw)
 
 
 def _decide_gram(d, dtype_name, machine, ctx, kw) -> ExecutionPlan:

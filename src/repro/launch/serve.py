@@ -489,6 +489,10 @@ class SolverServer:
     def step(self) -> list[api.Result]:
         """One scheduler tick: admit, then one solver iteration per active
         group; returns the requests that completed this tick."""
+        with jax.default_matmul_precision(api.F32_MATMULS):
+            return self._step()
+
+    def _step(self) -> list[api.Result]:
         self._c["steps"].inc()
         out = self._admit()
         if self._queue:

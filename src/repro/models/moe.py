@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from .config import ModelConfig
 from .layers import _dense_init, pdtype
 from .sharding import batch_axes, current_mesh
@@ -171,7 +170,7 @@ def apply_moe(p, x: Array, cfg: ModelConfig):
 
             g_spec = P("model", None, dp)
             d_spec = P("model", dp, None)
-            out, aux = compat.shard_map(
+            out, aux = jax.shard_map(
                 body2, mesh=mesh,
                 in_specs=(P(dp, None, None), P(None, None), g_spec, g_spec,
                           d_spec),
@@ -190,7 +189,7 @@ def apply_moe(p, x: Array, cfg: ModelConfig):
                 return out.reshape(x_loc.shape), aux
 
             espec = P("model", None, None)
-            out, aux = compat.shard_map(
+            out, aux = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(dp, None, None), P(None, None), espec, espec,
                           espec),

@@ -12,7 +12,7 @@ from repro.kernels import ops, ref
 # sublane for second-to-last-dim knobs).
 KNOB_KIND = {
     "gemm": {"bm": "sublane", "bn": "lane", "bk": "lane"},
-    "tsgram": {"bm": "sublane"},
+    "tsgram": {"bm": "sublane", "bn": "lane"},
     "randsketch": {"bm": "sublane", "bn": "lane"},
     # fusedgrad's bm doubles as the lane width of its t/w/z vector strips,
     # so its candidates are lane-aligned.

@@ -147,7 +147,6 @@ def test_psum_int8_distributed_round_trip():
     within the per-shard quantization bound, and the EF identity must hold
     per shard."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     devs = np.array(jax.devices()[:1])
     mesh = Mesh(devs.reshape(1), ("data",))
@@ -161,9 +160,9 @@ def test_psum_int8_distributed_round_trip():
         def body(xs, rs):
             out, nr = psum_int8(xs[0], rs[0], ("data",), nshards)
             return out[None], nr[None]
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P("data", None), P("data", None)),
-                         out_specs=(P("data", None), P("data", None)))(x, res)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P("data", None), P("data", None)),
+                             out_specs=(P("data", None), P("data", None)))(x, res)
 
     out, new_res = run(x, res)
     true_sum = np.asarray(x).sum(0)

@@ -70,8 +70,7 @@ MINI_DRYRUN = textwrap.dedent("""
         plan = make_plan("qwen3-4b", shape, mesh, overrides=ov,
                          microbatches=1)
         compiled = plan.lower().compile()
-        from repro import compat
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         assert cost.get("flops", 0) > 0
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes > 0

@@ -17,6 +17,7 @@ from repro.core.tfocs import (CountingLinop, LinopMatrix, ProxZero,
                               fused_gradient_enabled, row_separable, tfocs)
 from repro.kernels import ops, ref
 from repro.kernels.bsr import BlockELL
+from repro.launch import planner
 
 
 def _data(m, n, dtype, seed=0, weighted=True):
@@ -84,7 +85,8 @@ class TestKernelParity:
         dense = (np.kron(mask, np.ones((8, 8)))
                  * rng.normal(size=(32, 88))).astype(np.float32)
         bell = BlockELL.from_dense(dense, bs=8)
-        assert fg.fused_grad_bsr_vmem(bell) > 2048
+        assert fg.fused_grad_bsr_vmem(bell.bs, bell.ell, bell.shape[1],
+                                       bell.data.dtype) > 2048
         monkeypatch.setattr(at, "VMEM_BUDGET", 2048)
         x = jnp.asarray(rng.normal(size=88), jnp.float32)
         t = jnp.asarray(rng.normal(size=32), jnp.float32)
@@ -273,6 +275,58 @@ class TestSolverStructure:
         assert wrapped.counts["apply"] > 0
         assert np.all(np.isfinite(np.asarray(x)))
 
+    def test_sparse_operand_fuses_past_the_dense_width(self):
+        """At n where no dense fused row block fits VMEM the planner picks
+        the two passes for a dense shard; a block-sparse operand is priced
+        on its stored blocks and takes its one-read BSR kernel — but not
+        once quantized to int8, which has no fused kernel."""
+        m, n = 256, 1 << 17
+        rng = np.random.default_rng(7)
+        ri = rng.integers(0, m, 64)
+        ci = rng.integers(0, n, 64)
+        S = SparseRowMatrix.from_entries(ri, ci, np.ones(64, np.float32),
+                                         (m, n), bs=128)
+        linop = LinopMatrix(S)
+        smooth = SmoothQuad(b=linop.pad_data(jnp.zeros(m)),
+                            weights=linop.row_weights())
+        assert planner.plan("grad", {"m": m, "n": n}).choice == "unfused"
+        assert linop.block_layout() == (S.bs, S.ell)
+        assert fused_gradient_enabled(smooth, linop, "auto")
+        assert fused_gradient_enabled(smooth, CountingLinop(linop), "auto")
+        q = LinopMatrix(S.astype_store("int8"))
+        assert not fused_gradient_enabled(smooth, q, "auto")
+        assert not fused_gradient_enabled(smooth, CountingLinop(q), "auto")
+        # The solver reports the engine that ran: two passes per attempt.
+        _, info = tfocs(smooth, q, ProxZero(), jnp.zeros(n),
+                        TfocsOptions(max_iters=3, accel=False))
+        assert info["plan"] != "fused" and not bool(info["fused"])
+        assert int(info["a_passes"]) == 1 + 2 * (
+            int(info["iterations"]) + int(info["n_backtracks"]))
+
+    @pytest.mark.parametrize("dtype,n,choice", [
+        ("float32", 1 << 16, "fused"),
+        ("int8", 1 << 16, "unfused"),        # no scale-aware fused kernel
+        ("float32", 1 << 23, "unfused"),     # x + accumulator outgrow VMEM
+    ])
+    def test_sparse_grad_plan(self, dtype, n, choice):
+        """plan("grad", kind sparse) prices one read of the stored blocks
+        against two, and offers the fused side only where the BSR fused
+        kernel runs (kernels/ops.fused_grad_bsr composes the two passes
+        elsewhere)."""
+        from repro.kernels import autotune as at
+        from repro.kernels import fusedgrad as fg
+        fits = fg.fused_grad_bsr_vmem(128, 5, n, dtype) <= at.VMEM_BUDGET
+        assert fits == (n == 1 << 16 or dtype == "int8")
+        p = planner.plan("grad", {"m": 1 << 20, "n": n, "bs": 128, "ell": 5},
+                         dtype, context={"kind": "sparse"})
+        assert p.choice == choice
+        names = {lb for lb, _ in p.alternatives}
+        assert names == ({"fused", "unfused"} if choice == "fused"
+                         else {"unfused"})
+        if choice == "fused":
+            costs = dict(p.alternatives)
+            assert costs["unfused"] > 1.9 * costs["fused"]
+
     def test_opt_out_flag(self):
         smooth, linop = self._composite()
         assert fused_gradient_enabled(smooth, linop, "auto")
@@ -307,15 +361,35 @@ class TestSolverParity:
         b = rng.normal(size=80).astype(np.float32)
         smooth = SmoothQuad(b=linop.pad_data(jnp.asarray(b)),
                             weights=linop.row_weights())
-        outs = {}
+        # Backtracking here accepts steps 1/L with L as low as λmax(AᵀA)/5
+        # (about half of 80 steps have L < λmax/2), so any difference
+        # between the two engines' f32 roundings grows along the top
+        # eigenvector by |1 − λmax/L| per such step: the backtracking
+        # iterates agree to 1e-7 after 40 iterations and by 3e-3 after 80,
+        # while the objective and every accept/reject decision still agree.
+        # Both engines are held to all three, and the iterates to the
+        # tolerance also after 80 steps at the fixed step 1/λmax.
+        L = float(np.linalg.norm(A, 2)) ** 2
+        run = {}
         for fused in (True, False):
-            outs[fused] = tfocs(
-                smooth, linop, ProxZero(), jnp.zeros(32),
-                TfocsOptions(max_iters=80, accel=False, backtracking=True,
-                             fused=fused))[0]
-        np.testing.assert_allclose(np.asarray(outs[True]),
-                                   np.asarray(outs[False]),
-                                   rtol=1e-4, atol=1e-5)
+            for name, opts in (
+                    ("fixed80", dict(max_iters=80, Lexact=L)),
+                    ("bt40", dict(max_iters=40, backtracking=True)),
+                    ("bt80", dict(max_iters=80, backtracking=True))):
+                x, info = tfocs(smooth, linop, ProxZero(), jnp.zeros(32),
+                                TfocsOptions(accel=False, fused=fused,
+                                             **opts))
+                assert bool(info["fused"]) == fused
+                run[name, fused] = (np.asarray(x), info)
+        for name in ("fixed80", "bt40"):
+            np.testing.assert_allclose(run[name, True][0],
+                                       run[name, False][0],
+                                       rtol=1e-4, atol=1e-5)
+        for name in ("bt40", "bt80"):
+            fi, ui = run[name, True][1], run[name, False][1]
+            assert int(fi["n_backtracks"]) == int(ui["n_backtracks"])
+            np.testing.assert_allclose(np.asarray(fi["history"]),
+                                       np.asarray(ui["history"]), rtol=1e-4)
 
     def test_counting_linop_is_transparent(self):
         p = make_problem("linear", m=200, n=32)

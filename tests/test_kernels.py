@@ -79,6 +79,32 @@ def test_bsr_property(bm, bn, density):
     np.testing.assert_allclose(got, dense @ x, rtol=1e-4, atol=1e-3)
 
 
+@pytest.mark.parametrize("quantize", ["none", "int8"])
+@pytest.mark.parametrize("rows", [2, 3])
+def test_bsr_matmul_prefetch_chunks(monkeypatch, quantize, rows):
+    """With the scalar-prefetched column list capped at `rows` block-rows,
+    bsr_matmul runs several chunks into one aliased output, the last one
+    ragged (7 block-rows), for exact and int8 blocks alike."""
+    from repro.kernels import bsr
+    rng = np.random.default_rng(31 + rows)
+    mask = rng.random((7, 5)) < 0.5
+    mask[:, 0] = True
+    dense = (np.kron(mask, np.ones((8, 8)))
+             * rng.normal(size=(56, 40))).astype(np.float32)
+    bell = BlockELL.from_dense(dense, bs=8, quantize=quantize)
+    assert (bell.scales is not None) == (quantize == "int8")
+    monkeypatch.setattr(bsr, "PREFETCH_WORDS", rows * bell.ell)
+    assert 7 % rows and -(-7 // rows) > 1    # several chunks, ragged tail
+    x = jnp.asarray(rng.normal(size=(40, 16)), jnp.float32)
+    bsr.bsr_matmul.clear_cache()             # the cap is read at trace time
+    try:
+        got = ops.bsr_matmul(bell, x, force_pallas=True)
+    finally:
+        bsr.bsr_matmul.clear_cache()
+    np.testing.assert_allclose(got, ref.bsr_matmul_ref(bell, x), rtol=1e-4,
+                               atol=1e-3)
+
+
 @given(st.integers(1, 6), st.integers(1, 6), st.floats(0.1, 0.9))
 @settings(max_examples=8, deadline=None)
 def test_bsr_matvec_property(bm, bn, density):

@@ -1,8 +1,12 @@
 """Compute/collective overlap: the chunked (double-buffered) gram and
-fused_grad bodies must be BIT-identical to the eager path — the chunks
-split columns, and `dot(aᵀ, a[:, seg])` concatenated over segments is the
-same float sequence as `dot(aᵀ, a)` — while issuing the segmented psum
-structure the planner schedules.  Parity is pinned in-process on one
+fused_grad bodies must agree with the eager path while issuing the
+segmented psum structure the planner schedules.  The chunks split
+columns: `dot(aᵀ, a[:, seg])` is the same sum as the matching columns of
+`dot(aᵀ, a)`, but a separate contraction that XLA may block (and so round)
+differently.  Segmented products are therefore held to the f32 forward
+error bound of a length-m dot product, |Δ| ≤ 2·m·eps·(|a|ᵀ|b|), for the
+two summation orders; values computed by the same contraction on both
+paths (f, z) stay bit-identical.  Parity is pinned in-process on one
 device and in a subprocess on a real 8-device host mesh."""
 import os
 import subprocess
@@ -17,6 +21,16 @@ import pytest
 from repro.core.distmat import RowMatrix, SparseRowMatrix
 from repro.core.distmat.rowmatrix import chunk_bounds
 from repro.core.tfocs import SmoothQuad, LinopMatrix, row_separable
+
+
+def assert_f32_dot_close(got, want, lhs, rhs):
+    """|got − want| within the two-order f32 forward error bound of the
+    contraction lhsᵀ·rhs over its m rows (see the module docstring)."""
+    lhs, rhs = np.abs(np.asarray(lhs, np.float64)), np.abs(
+        np.asarray(rhs, np.float64))
+    bound = 2 * lhs.shape[0] * np.finfo(np.float32).eps * (lhs.T @ rhs)
+    err = np.abs(np.asarray(got, np.float64) - np.asarray(want, np.float64))
+    assert np.all(err <= bound), float((err - bound).max())
 
 
 def _problem(m=96, n=24, seed=3):
@@ -46,16 +60,17 @@ class TestBitParity:
         A, rm, _, _ = _problem()
         eager = np.asarray(rm.gram(chunks=1))
         for c in (2, 4, 8):
-            assert np.array_equal(np.asarray(rm.gram(chunks=c)), eager), c
+            assert_f32_dot_close(rm.gram(chunks=c), eager, A, A)
         np.testing.assert_allclose(eager, A.T @ A, rtol=1e-4, atol=1e-4)
 
     def test_fused_grad_chunked_matches_eager(self):
-        _, rm, sep, x = _problem()
+        A, rm, sep, x = _problem()
         f1, g1, z1 = rm.fused_grad(x, sep, chunks=1)
+        r = np.asarray(z1)[:A.shape[0]] - np.asarray(sep.target)[:A.shape[0]]
         for c in (2, 4):
             fc, gc, zc = rm.fused_grad(x, sep, chunks=c)
             assert np.array_equal(np.asarray(fc), np.asarray(f1))
-            assert np.array_equal(np.asarray(gc), np.asarray(g1))
+            assert_f32_dot_close(gc, g1, r[:, None], A)
             assert np.array_equal(np.asarray(zc), np.asarray(z1))
 
     def test_auto_resolves_to_eager_on_one_device(self):
@@ -130,15 +145,20 @@ EIGHT_DEV_SCRIPT = textwrap.dedent("""
                                    weights=linop.row_weights()))
     x = jnp.asarray(rng.normal(size=24), jnp.float32)
 
+    def close(got, want, lhs, rhs):     # the f32 two-order dot bound
+        lhs, rhs = np.abs(np.float64(lhs)), np.abs(np.float64(rhs))
+        bound = 2 * lhs.shape[0] * np.finfo(np.float32).eps * (lhs.T @ rhs)
+        assert np.all(np.abs(np.float64(got) - np.float64(want)) <= bound)
+
     eager = np.asarray(rm.gram(chunks=1))
     for c in (2, 4):
-        assert np.array_equal(np.asarray(rm.gram(chunks=c)), eager), c
+        close(rm.gram(chunks=c), eager, A, A)
     np.testing.assert_allclose(eager, A.T @ A, rtol=1e-3, atol=1e-3)
 
     f1, g1, z1 = rm.fused_grad(x, sep, chunks=1)
     fc, gc, zc = rm.fused_grad(x, sep, chunks=4)
     assert np.array_equal(np.asarray(fc), np.asarray(f1))
-    assert np.array_equal(np.asarray(gc), np.asarray(g1))
+    close(gc, g1, (np.asarray(z1)[:96] - b)[:, None], A)
     assert np.array_equal(np.asarray(zc), np.asarray(z1))
 
     mask = rng.random((8, 6)) < 0.4
@@ -154,7 +174,7 @@ EIGHT_DEV_SCRIPT = textwrap.dedent("""
         f1, g1, z1 = srm.fused_grad(xs, seps, dispatch=dispatch, chunks=1)
         fc, gc, zc = srm.fused_grad(xs, seps, dispatch=dispatch, chunks=4)
         assert np.array_equal(np.asarray(fc), np.asarray(f1)), dispatch
-        assert np.array_equal(np.asarray(gc), np.asarray(g1)), dispatch
+        close(gc, g1, (np.asarray(z1)[:64] - bs)[:, None], S)
         assert np.array_equal(np.asarray(zc), np.asarray(z1)), dispatch
     print("OVERLAP_8DEV_OK")
 """)

@@ -70,7 +70,7 @@ RANK_GOLD = [
      {"bm": 512, "bn": 512, "bk": 1024}),
     ("gemm", {"m": 10000, "k": 1000, "n": 1000},
      {"bm": 512, "bn": 512, "bk": 1024}),
-    ("tsgram", {"m": 16384, "n": 256}, {"bm": 1024}),
+    ("tsgram", {"m": 16384, "n": 256}, {"bm": 1024, "bn": 256}),
     ("fusedgrad", {"m": 10000, "n": 1024}, {"bm": 1024}),
     ("randsketch", {"m": 16384, "n": 2048, "r": 72},
      {"bm": 1024, "bn": 1024}),
@@ -384,8 +384,8 @@ class TestCommPlanning:
     def test_gram_overlap_past_break_even(self):
         """Chunked overlap engages only once the modeled psum is worth
         hiding: eager on few devices, overlapped on many."""
-        few = planner.plan("gram", {"m": 1_000_000 // 4, "n": 1024},
-                           machine=machine.V5E, context={"axes": (4,)})
+        few = planner.plan("gram", {"m": 1_000_000 // 2, "n": 1024},
+                           machine=machine.V5E, context={"axes": (2,)})
         many = planner.plan("gram", {"m": 1_000_000 // 64, "n": 1024},
                             machine=machine.V5E, context={"axes": (64,)})
         assert few.choice == "eager" and few.blocks["chunks"] == 1
