@@ -1,0 +1,6 @@
+"""Mean streaming passes over A per solve job (`info["a_passes"]`)."""
+from metrics._common import passes_per_job
+
+
+def read(run):
+    return passes_per_job(run)
