@@ -195,9 +195,11 @@ def traced_demo(out_dir: str = "bench-artifacts",
                 delay_s: float = 0.02) -> dict:
     """End-to-end traced episode for the CI trace artifact: a batched
     served solve plus an elastic fault episode (straggler → trip →
-    checkpoint → re-mesh) recorded under one telemetry Recorder, exported
-    as JSONL events and a Chrome/Perfetto trace.  Returns the summary so
-    the caller (and CI log) can see the span-tree phase coverage."""
+    checkpoint → re-mesh) recorded under one telemetry Recorder and the
+    JAX profiler, exported as JSONL events and a profiler trace (its
+    ``repro.*`` spans beside the device ops; open the directory in
+    TensorBoard or Perfetto).  Returns the summary so the caller (and CI
+    log) can see the span-tree phase coverage."""
     import pathlib
     import tempfile
 
@@ -215,7 +217,9 @@ def traced_demo(out_dir: str = "bench-artifacts",
 
     rec = telemetry.Recorder()
     A, bs = _trace(m, n, k, seed=3)
-    with telemetry.recording(rec):
+    out = pathlib.Path(out_dir)
+    profile_dir = out / "profile"
+    with telemetry.recording(rec), jax.profiler.trace(str(profile_dir)):
         # -- served group solve: admit/queue-wait/latency/retire spans ---
         server = SolverServer(slots=k)
         _serve(server, A, bs, max_iters=60)
@@ -246,13 +250,11 @@ def traced_demo(out_dir: str = "bench-artifacts",
                 if grp.remeshes >= 1 and grp.iteration >= 20:
                     break
 
-    out = pathlib.Path(out_dir)
     out.mkdir(parents=True, exist_ok=True)
     rec.export_jsonl(out / "telemetry_events.jsonl")
-    rec.export_chrome_trace(out / "trace.perfetto.json")
     summary = rec.summary()
     summary["artifacts"] = [str(out / "telemetry_events.jsonl"),
-                            str(out / "trace.perfetto.json")]
+                            str(profile_dir)]
     return summary
 
 
@@ -318,7 +320,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--traced-demo", action="store_true",
                     help="record a traced served solve + fault episode and "
-                         "export JSONL + Perfetto trace artifacts")
+                         "export JSONL + profiler trace artifacts")
     ap.add_argument("--out-dir", default="bench-artifacts")
     args = ap.parse_args()
     if args.traced_demo:

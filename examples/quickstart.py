@@ -318,8 +318,10 @@ lat = traced_srv.tel.histogram("serve.latency_s")
 print(f"served p50 latency: {lat.percentile(0.5) * 1e3:.1f} ms "
       f"(stats: {traced_srv.stats})")
 
-# Exports: rec.export_jsonl(path) writes one JSON event per line;
-# rec.export_chrome_trace(path) writes a Chrome/Perfetto trace (open at
+# Exports: rec.export_jsonl(path) writes one JSON event per line.  For a
+# timeline, run the work under jax.profiler.trace(dir): every span is a
+# "repro.<name>" annotation there, beside the device ops, with or without
+# a recorder (open the directory in TensorBoard or at
 # https://ui.perfetto.dev).  rec.calibration_records() feeds
 # planner.calibrate() so the cost model learns from production traces —
 # the same loop benchmarks/bench_serve.py --traced-demo packages for CI.

@@ -50,6 +50,7 @@ from repro.core.tfocs.smooth import (SmoothHuber, SmoothLogLoss,
                                      SmoothPoisson, SmoothQuad)
 from repro.kernels import autotune as _autotune
 from repro.kernels.fusedgrad import LOSSES
+from repro.launch import telemetry as _telemetry
 
 Array = jax.Array
 
@@ -275,15 +276,19 @@ F32_MATMULS = _autotune.HIGHEST.name.lower()
 
 
 def _traced(req, kind: str, run) -> Result:
-    """Run one job with f32 matmuls.  The ``telemetry=`` escape hatch: when
-    the request asks for it, run the job under a scoped recorder (every
-    instrumented component — elastic iterations, checkpoints, stragglers —
-    resolves it via telemetry.current()) and attach the compact summary as
-    ``Result.info["trace"]``.  Off (the default) adds no work at all."""
+    """Run one job with f32 matmuls, inside an ``api.<kind>`` span with
+    the request's id (on the profiler's clock on every call).  The
+    ``telemetry=`` escape hatch: when the request asks for it, run the job
+    under a scoped recorder (every instrumented component — elastic
+    iterations, checkpoints, stragglers — resolves it via
+    telemetry.current()) and attach the compact summary as
+    ``Result.info["trace"]``.  Off (the default), the span is a profiler
+    annotation and nothing else."""
     with jax.default_matmul_precision(F32_MATMULS):
         if not req.telemetry:
-            return run()
-        from repro.launch import telemetry as _telemetry
+            with _telemetry.current().span("api." + kind,
+                                           request_id=req.request_id):
+                return run()
         rec = req.telemetry \
             if isinstance(req.telemetry, _telemetry.Recorder) \
             else _telemetry.Recorder()
@@ -334,11 +339,12 @@ def _solve(req: SolveRequest, *, fused: bool | str = "auto") -> Result:
 
     from repro.core.optim.first_order import minimize_first_order
     from repro.core.tfocs.solver import TfocsOptions
-    linop = solve_linop(req)
-    smooth = solve_smooth(req, linop)
-    prox = solve_prox(req)
-    x0 = jnp.zeros(linop.in_shape, jnp.float32) if req.x0 is None \
-        else jnp.asarray(req.x0, jnp.float32)
+    with _telemetry.current().span("solve.setup"):
+        linop = solve_linop(req)
+        smooth = solve_smooth(req, linop)
+        prox = solve_prox(req)
+        x0 = jnp.zeros(linop.in_shape, jnp.float32) if req.x0 is None \
+            else jnp.asarray(req.x0, jnp.float32)
     opts = TfocsOptions(max_iters=req.max_iters, tol=req.tol, L0=req.L0,
                         fused=fused, precision=req.precision)
     if req.method == "lbfgs" and not isinstance(prox, ProxZero):

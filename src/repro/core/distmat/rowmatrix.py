@@ -46,7 +46,9 @@ def chunk_bounds(n: int, chunks: int) -> tuple[tuple[int, int], ...]:
 def _record_collective(plan, span, **attrs) -> None:
     """Plan-vs-actual for one distributed op: the span's synced duration
     next to the comm-priced plan (launch/telemetry collects the records;
-    their comm terms feed MachineModel.calibrate's link column)."""
+    their comm terms feed MachineModel.calibrate's link column).  An op
+    traced into a loop body has a named scope for a span, with no
+    duration, and writes no record: its clock would time the tracer."""
     from repro.launch import telemetry as _tel
     rec = _tel.current()
     if rec.enabled and span.dur_s > 0:

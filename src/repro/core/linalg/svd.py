@@ -45,6 +45,7 @@ import numpy as np
 from repro.core.distmat.coordinatematrix import CoordinateMatrix
 from repro.core.distmat.rowmatrix import RowMatrix
 from repro.core.distmat.sparserow import SparseRowMatrix
+from repro.launch import telemetry as _telemetry
 from . import lanczos as _lanczos
 from . import randsvd as _randsvd
 
@@ -72,10 +73,16 @@ def driver_eigh(G: Array, k: int | None = None) -> tuple[Array, Array]:
     """Eigenpairs of the replicated n × n Gram, largest first (top k), on
     the driver: LAPACK in float64 on the host, as the paper runs it.  (The
     TPU's own eigh compiles for minutes and tens of GB of host memory at
-    n in the thousands.)"""
-    w, V = np.linalg.eigh(np.asarray(jax.device_get(G), np.float64))
-    w, V = w[::-1][:k], V[:, ::-1][:, :k]
-    return jnp.asarray(w, jnp.float32), jnp.asarray(V, jnp.float32)
+    n in the thousands.)  Spans: ``svd.fetch`` waits for the Gram and
+    copies it to the host, ``svd.eigh`` is the host LAPACK and the copy
+    back."""
+    tel = _telemetry.current()
+    with tel.span("svd.fetch"):
+        G = jax.device_get(G)
+    with tel.span("svd.eigh"):
+        w, V = np.linalg.eigh(np.asarray(G, np.float64))
+        w, V = w[::-1][:k], V[:, ::-1][:, :k]
+        return jnp.asarray(w, jnp.float32), jnp.asarray(V, jnp.float32)
 
 
 def _recover_u(A, s: Array, V: Array, rcond: float) -> RowMatrix:
@@ -194,9 +201,10 @@ def compute_svd(A, k: int, *, compute_u: bool = True,
                     iterations=info["restarts"],
                     a_passes=2 * info["op_calls"])
 
-    U = _recover_u(A, s, V, rcond) if (
-        compute_u and isinstance(A, (RowMatrix, SparseRowMatrix))) else None
-    if U is not None:
+    U = None
+    if compute_u and isinstance(A, (RowMatrix, SparseRowMatrix)):
+        with _telemetry.current().span("svd.recover_u"):
+            U = _recover_u(A, s, V, rcond)
         info = dict(info, a_passes=info["a_passes"] + 1)  # the U = A(VΣ⁻¹) pass
     return SVDResult(U=U, s=s, V=V, info=info)
 

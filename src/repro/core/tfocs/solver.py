@@ -48,6 +48,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.launch import telemetry as _telemetry
+
 from .smooth import row_separable
 
 Array = jax.Array
@@ -493,7 +495,9 @@ def tfocs(smooth, linop, prox, x0: Array,
           opts: TfocsOptions = TfocsOptions()) -> tuple[Array, dict]:
     """Run the solver; returns (x*, info dict with per-iteration history).
     info["precision"] reports the resolved compute/wire precision (see
-    TfocsOptions.precision)."""
+    TfocsOptions.precision).  The call into the chosen engine is a
+    ``solve.loop`` span: the eager seed pass, building and dispatching the
+    while_loop; it closes before the device finishes."""
     prec = resolve_precision(linop, opts)
     if prec == "bf16":
         if hasattr(linop, "astype_store"):
@@ -508,8 +512,9 @@ def tfocs(smooth, linop, prox, x0: Array,
                 if hasattr(linop, "init_psum_residual") else None
             if residual is None:
                 prec = "f32"     # local operand: no wire to compress
-        x, info = _tfocs_fused(smooth, linop, prox, x0, opts,
-                               row_separable(smooth), residual=residual)
+        with _telemetry.current().span("solve.loop"):
+            x, info = _tfocs_fused(smooth, linop, prox, x0, opts,
+                                   row_separable(smooth), residual=residual)
         info["precision"] = prec
         return x, info
     if prec == "psum8":
@@ -518,7 +523,8 @@ def tfocs(smooth, linop, prox, x0: Array,
     if (opts.accel and sep is not None and sep.kind == "quad"
             and _fused_capable(linop)
             and fused_gradient_enabled(smooth, linop, opts.fused)):
-        x, info = _tfocs_fused_accel(smooth, linop, prox, x0, opts, sep)
+        with _telemetry.current().span("solve.loop"):
+            x, info = _tfocs_fused_accel(smooth, linop, prox, x0, opts, sep)
         info["precision"] = prec
         return x, info
     backtracking = opts.backtracking and opts.Lexact is None
@@ -606,15 +612,16 @@ def tfocs(smooth, linop, prox, x0: Array,
     def cond(state: TfocsState):
         return (~state.done) & (state.k < opts.max_iters)
 
-    Ax0 = linop.apply(x0)
-    init = TfocsState(
-        x=x0, Ax=Ax0, z=x0, Az=Ax0,
-        theta=jnp.asarray(1.0, jnp.float32), L=L_init,
-        k=jnp.int32(0),
-        hist=jnp.full((opts.max_iters,), jnp.nan, jnp.float32),
-        done=jnp.asarray(False),
-        n_backtracks=jnp.int32(0), n_restarts=jnp.int32(0))
-    final = jax.lax.while_loop(cond, outer, init)
+    with _telemetry.current().span("solve.loop"):
+        Ax0 = linop.apply(x0)
+        init = TfocsState(
+            x=x0, Ax=Ax0, z=x0, Az=Ax0,
+            theta=jnp.asarray(1.0, jnp.float32), L=L_init,
+            k=jnp.int32(0),
+            hist=jnp.full((opts.max_iters,), jnp.nan, jnp.float32),
+            done=jnp.asarray(False),
+            n_backtracks=jnp.int32(0), n_restarts=jnp.int32(0))
+        final = jax.lax.while_loop(cond, outer, init)
     # Standardized keys as in _tfocs_fused; the cached accelerated scheme
     # pays apply + adjoint (two passes) per attempt, plus the seed apply.
     info = {"iterations": final.k,

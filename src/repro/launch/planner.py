@@ -71,6 +71,7 @@ from typing import Mapping
 
 from repro.kernels import autotune as at
 from repro.launch import machine as _machine
+from repro.launch import telemetry as _telemetry
 from repro.launch.machine import LANE, CostTerms, MachineModel
 
 KERNEL_OPS = tuple(at.KERNELS)
@@ -194,7 +195,13 @@ def plan(op: str, dims: Mapping[str, int], dtype="float32", *,
     calibrated-model lookup (and bypasses the decision memo).  `top` > 0
     attaches the top-N ranked block configs as alternatives for kernel ops.
     `context` carries op-specific non-shape inputs (see module docstring).
+    Each decision is a ``planner.plan`` span.
     """
+    with _telemetry.current().span("planner.plan", op=op):
+        return _plan(op, dims, dtype, backend, machine, context, top)
+
+
+def _plan(op, dims, dtype, backend, machine, context, top) -> ExecutionPlan:
     import jax
     import jax.numpy as jnp
     backend = backend or jax.default_backend()

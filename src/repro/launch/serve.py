@@ -64,8 +64,11 @@ and ``serve.latency_s`` histograms with real p50/p99.  Scheduler-action
 spans (admit / oneshot / retire / shed / recover) and the solver's
 per-iteration spans are recorded when the server is constructed while
 ``telemetry.enable()`` is in effect (or given an explicit ``telemetry=``
-recorder); export with ``server.tel.export_chrome_trace(path)``.  See the
-"observability" section of examples/quickstart.py.
+recorder); export them with ``server.tel.export_jsonl(path)``.  Every span
+is also a ``repro.*`` annotation on the JAX profiler's clock, recorder or
+not: run the server under ``jax.profiler.trace(dir)`` for a timeline of
+the scheduler actions beside the device ops.  See the "observability"
+section of examples/quickstart.py.
 """
 from __future__ import annotations
 

@@ -8,15 +8,23 @@ calibrated performance model — and ``MachineModel.calibrate()`` closes the
 same loop here: every traced solve emits plan-vs-actual records that
 ``planner.calibrate()`` accepts directly.
 
-Zero-dependency (stdlib only at import; jax is imported lazily for the
-optional device sync), three layers:
+Stdlib only at import; jax is looked up on the first span (and for the
+optional device sync).  Three layers:
 
-  * **Spans** — nestable, thread-safe wall-clock intervals around every
-    elastic-solver iteration phase (fused A-pass, seed pass, host
-    validation, checkpoint write, re-mesh/re-JIT) and every server
-    scheduler action (admit, join, retire, shed, recover).  ``sync_on()``
-    blocks on a device payload before the span closes so the recorded
-    duration covers the device work, not just the dispatch.
+  * **Spans** — nestable, thread-safe wall-clock intervals at the layer
+    boundaries of a job (api request, planner decision, solver set-up and
+    loop, distributed ops, the SVD driver's Gram fetch, ``eigh`` and U
+    recovery), around every elastic-solver iteration phase and every
+    server scheduler action.  Every span is also a
+    ``jax.profiler.TraceAnnotation`` named ``"repro." + name`` (with the
+    span's ``request_id`` as metadata, where it has one), so a profiler
+    trace shows it on the device's clock beside the device ops — with or
+    without a recorder.  Under a JAX trace (a jitted function, a
+    ``while_loop`` body, a ``shard_map`` body) a span is a
+    ``jax.named_scope`` instead and records nothing: its clock would time
+    the tracer, not the work.  ``sync_on()`` blocks on a device payload
+    before a recorded span closes so the duration covers the device work,
+    not just the dispatch.
 
   * **Metrics** — a registry of counters, gauges and histograms with FIXED
     log-spaced buckets (two histograms are always mergeable/comparable),
@@ -30,23 +38,24 @@ optional device sync), three layers:
     ``calibration_records()`` feeds straight into ``planner.calibrate()``
     and modeled-vs-measured drift is visible in ``Result.info["trace"]``.
 
-Exporters: ``snapshot()`` (in-memory, JSON-safe), ``export_jsonl(path)``
-(one event per line), and ``export_chrome_trace(path)`` (Chrome/Perfetto
-``traceEvents`` — load in https://ui.perfetto.dev for the span timeline).
+Exporters: ``snapshot()`` (in-memory, JSON-safe) and ``export_jsonl(path)``
+(one event per line).  For a timeline, run the work under
+``jax.profiler.trace(dir)``: the spans are in it beside the device ops.
 
-Everything is OFF by default with near-zero overhead: the module-level
-recorder is a ``NullRecorder`` whose ``span()`` returns one shared no-op
-context manager and whose metric handles do nothing.  Components resolve
-``current()`` at call time, so
+Recording is OFF by default: the module-level recorder is a
+``NullRecorder`` whose ``span()`` opens only the profiler annotation (no
+bookkeeping, no device sync; about a microsecond) and whose metric
+handles are one shared no-op.  Components resolve ``current()`` at call
+time, so
 
     rec = telemetry.enable()           # or: with telemetry.recording() as rec
     ... run solves / serve requests ...
-    rec.snapshot(); rec.export_chrome_trace("trace.json")
+    rec.snapshot(); rec.export_jsonl("events.jsonl")
 
 instruments the whole stack without threading a recorder through every
 constructor (explicit ``telemetry=`` parameters on the api request objects
 and SolverServer override the module default).  See the "observability"
-section of examples/quickstart.py for the walkthrough.
+section of examples/quickstart.py.
 """
 from __future__ import annotations
 
@@ -176,6 +185,21 @@ class Histogram:
 
 # -- spans --------------------------------------------------------------------
 
+# Prefix of every span's name on the profiler's timeline.
+PROFILER_PREFIX = "repro."
+# jax.profiler.TraceAnnotation, jax.named_scope and jax.core.trace_ctx,
+# looked up on the first span so that importing this module needs no jax.
+_annotation_cls = _named_scope = _trace_ctx = None
+
+
+def _load_jax() -> None:
+    global _annotation_cls, _named_scope, _trace_ctx
+    import jax
+    _annotation_cls = jax.profiler.TraceAnnotation
+    _named_scope = jax.named_scope
+    _trace_ctx = jax.core.trace_ctx
+
+
 @dataclass
 class Span:
     """One closed interval on one thread's span stack."""
@@ -189,12 +213,15 @@ class Span:
 
 
 class _SpanCtx:
-    """Context manager for one span; created by Recorder.span()."""
-    __slots__ = ("_rec", "_span", "_t0", "_payload")
+    """Context manager for one recorded span; created by Recorder.span()
+    outside any JAX trace.  It holds the span's profiler annotation open
+    for as long as it times the span."""
+    __slots__ = ("_rec", "_span", "_t0", "_payload", "_mark")
 
-    def __init__(self, rec: "Recorder", name: str, attrs: dict):
+    def __init__(self, rec: "Recorder", name: str, attrs: dict, mark):
         self._rec = rec
         self._payload = None
+        self._mark = mark
         tid = threading.get_ident()
         stack = rec._stack()
         parent = stack[-1] if stack else None
@@ -216,6 +243,7 @@ class _SpanCtx:
         return self._span.dur_s
 
     def __enter__(self) -> "_SpanCtx":
+        self._mark.__enter__()
         self._rec._stack().append(self._span.id)
         self._t0 = time.perf_counter()
         self._span.t_start_s = self._t0 - self._rec.epoch
@@ -232,6 +260,7 @@ class _SpanCtx:
             self._span.attrs["error"] = f"{exc_type.__name__}: {exc}" \
                 if exc is not None else exc_type.__name__
         self._rec._commit(self._span)
+        self._mark.__exit__(exc_type, exc, tb)
 
 
 def _block_until_ready(payload) -> None:
@@ -242,11 +271,16 @@ def _block_until_ready(payload) -> None:
         pass
 
 
-class _NullSpanCtx:
-    """Shared no-op span: one module-level instance, zero allocation on the
-    disabled path."""
-    __slots__ = ()
+class _MarkCtx:
+    """A span that records nothing: it only enters its mark (a profiler
+    annotation, or a named scope under a JAX trace).  The span API's
+    annotate / sync_on are no-ops, so the caller's code runs exactly as
+    it would with no span at all."""
+    __slots__ = ("_mark",)
     dur_s = 0.0
+
+    def __init__(self, mark):
+        self._mark = mark
 
     def annotate(self, **attrs):
         return self
@@ -255,10 +289,11 @@ class _NullSpanCtx:
         return self
 
     def __enter__(self):
+        self._mark.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        return None
+        self._mark.__exit__(exc_type, exc, tb)
 
 
 class _NullMetric:
@@ -283,7 +318,6 @@ class _NullMetric:
         return {}
 
 
-_NULL_SPAN = _NullSpanCtx()
 _NULL_METRIC = _NullMetric()
 
 
@@ -293,10 +327,10 @@ class Recorder:
     """One telemetry sink: spans + metrics registry + plan-vs-actual log.
 
     ``spans=False`` keeps the metrics registry live but makes ``span()``
-    return the shared no-op context — the mode SolverServer uses for its
-    always-on counters.  ``max_spans`` bounds memory on long-lived
-    recorders: past it, new spans are dropped and counted in
-    ``spans_dropped``.
+    open only the profiler annotation, as the null recorder does — the
+    mode SolverServer uses for its always-on counters.  ``max_spans``
+    bounds memory on long-lived recorders: past it, new spans are dropped
+    and counted in ``spans_dropped``.
     """
     enabled = True
 
@@ -304,7 +338,6 @@ class Recorder:
         self.record_spans = spans
         self.max_spans = int(max_spans)
         self.epoch = time.perf_counter()
-        self.epoch_unix = time.time()
         self.spans: list[Span] = []
         self.spans_dropped = 0
         self._metrics: dict[str, Any] = {}
@@ -333,10 +366,21 @@ class Recorder:
             self.spans.append(span)
 
     def span(self, name: str, **attrs):
-        """Open a nested span; use as ``with rec.span("phase") as sp:``."""
+        """Open a nested span; use as ``with rec.span("phase") as sp:``.
+        It is a profiler annotation ``repro.<name>`` too, with the span's
+        ``request_id`` as metadata where it has one.  Under a JAX trace it
+        is a named scope and records nothing: its clock would time the
+        tracer, not the work."""
+        if _trace_ctx is None:
+            _load_jax()
+        if not _trace_ctx.is_top_level():
+            return _MarkCtx(_named_scope(name))
+        rid = attrs.get("request_id")
+        mark = _annotation_cls(PROFILER_PREFIX + name) if rid is None \
+            else _annotation_cls(PROFILER_PREFIX + name, request_id=rid)
         if not self.record_spans:
-            return _NULL_SPAN
-        return _SpanCtx(self, name, attrs)
+            return _MarkCtx(mark)
+        return _SpanCtx(self, name, attrs, mark)
 
     # -- metrics registry -----------------------------------------------------
 
@@ -466,47 +510,12 @@ class Recorder:
                 f.write(json.dumps(e, default=_json_default) + "\n")
         return len(evs)
 
-    def chrome_trace(self) -> dict:
-        """Chrome/Perfetto ``traceEvents`` document of the span timeline
-        (complete "X" events, µs timebase; one row per thread)."""
-        events: list[dict] = [
-            {"name": "process_name", "ph": "M", "pid": 0,
-             "args": {"name": "repro solver"}}]
-        tids = {}
-        with self._lock:
-            spans = list(self.spans)
-        for s in spans:
-            tid = tids.setdefault(s.tid, len(tids))
-            events.append({
-                "name": s.name, "ph": "X", "pid": 0, "tid": tid,
-                "ts": round(s.t_start_s * 1e6, 3),
-                "dur": round(s.dur_s * 1e6, 3),
-                "args": {k: _json_safe(v) for k, v in s.attrs.items()}})
-        for real_tid, tid in tids.items():
-            events.append({"name": "thread_name", "ph": "M", "pid": 0,
-                           "tid": tid,
-                           "args": {"name": f"thread-{real_tid}"}})
-        return {"traceEvents": events, "displayTimeUnit": "ms",
-                "otherData": {"epoch_unix_s": self.epoch_unix}}
-
-    def export_chrome_trace(self, path) -> int:
-        doc = self.chrome_trace()
-        with open(path, "w") as f:
-            json.dump(doc, f)
-        return len(doc["traceEvents"])
-
     def clear(self) -> None:
         with self._lock:
             self.spans.clear()
             self.spans_dropped = 0
             self._metrics.clear()
             self._plan_actual.clear()
-
-
-def _json_safe(v):
-    if isinstance(v, (str, int, float, bool)) or v is None:
-        return v
-    return str(v)
 
 
 def _json_default(v):
@@ -517,16 +526,13 @@ def _json_default(v):
 
 
 class NullRecorder(Recorder):
-    """The disabled default: every operation is a no-op returning shared
-    singletons — the near-zero-overhead path the escape hatches buy out
-    of."""
+    """The disabled default: a span is its profiler annotation alone, and
+    every other operation is a no-op returning a shared singleton — the
+    near-zero-overhead path the escape hatches buy out of."""
     enabled = False
 
     def __init__(self):
         super().__init__(spans=False, max_spans=0)
-
-    def span(self, name: str, **attrs):
-        return _NULL_SPAN
 
     def counter(self, name: str, **labels):
         return _NULL_METRIC

@@ -6,8 +6,11 @@ Three layers:
 
   * Recorder unit behavior — span nesting/attrs/errors/cap, counter and
     gauge and histogram math (fixed log-spaced buckets, interpolated
-    percentiles), the null recorder's zero-allocation no-ops, exporter
-    round-trips (JSONL, Chrome/Perfetto);
+    percentiles), the null recorder's annotation-only spans and shared
+    no-op metrics, the JSONL round-trip;
+  * the profiler's clock — every span is a ``repro.<name>`` annotation in
+    a ``jax.profiler`` trace, nested as the layers nest, with or without a
+    recorder, and nothing is recorded from code under a JAX trace;
   * integration — a traced api.solve carries ``info["trace"]`` with the
     solver span phases and fusedgrad plan-vs-actual records that
     ``planner.calibrate`` accepts; the served path renders per-reason
@@ -144,13 +147,14 @@ class TestMetrics:
 
 class TestNullRecorder:
     def test_noops_share_singletons(self):
-        """The disabled path allocates nothing per call: every span is the
-        same null context, every metric the same null sink."""
+        """The disabled path records nothing: a span is its profiler
+        annotation alone (annotate/sync_on are no-ops), every metric the
+        same null sink."""
         null = telemetry.NULL
         assert not null.enabled
         s1 = null.span("a", x=1)
         s2 = null.span("b")
-        assert s1 is s2
+        assert type(s1) is type(s2) and s1.dur_s == s2.dur_s == 0.0
         assert null.counter("c") is null.histogram("h")
         with null.span("a") as sp:
             sp.annotate(ok=True)
@@ -186,21 +190,6 @@ class TestExporters:
         assert {"span", "counter", "histogram"} <= kinds
         span = next(e for e in events if e["type"] == "span")
         assert span["name"] == "phase" and span["attrs"]["k"] == 1
-
-    def test_chrome_trace_structure(self, tmp_path):
-        rec = telemetry.Recorder()
-        with rec.span("outer"):
-            with rec.span("inner"):
-                pass
-        path = tmp_path / "trace.json"
-        rec.export_chrome_trace(path)
-        doc = json.loads(path.read_text())
-        events = doc["traceEvents"]
-        xs = [e for e in events if e["ph"] == "X"]
-        assert {e["name"] for e in xs} == {"outer", "inner"}
-        for e in xs:                       # µs timebase complete events
-            assert e["dur"] >= 0 and "ts" in e and "tid" in e
-        assert any(e["ph"] == "M" for e in events)   # metadata names
 
     def test_timeit_blocks_and_feeds_histogram(self):
         rec = telemetry.Recorder()
@@ -295,6 +284,117 @@ class TestTracedEntryPoints:
         assert "api.similarities" in r2.info["trace"]["phases"]
 
 
+def _profiled(tmp_path, fn):
+    """Run `fn` under jax.profiler.trace and return the host events named
+    ``repro.*`` as (name, start_ns, end_ns, stats)."""
+    import glob
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in data.planes if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("repro.")]
+
+
+def _inside(events, outer, inner):
+    """Every `inner` event lies in time inside some `outer` event."""
+    outs = [e for e in events if e[0] == outer]
+    ins = [e for e in events if e[0] == inner]
+    return bool(ins) and all(any(o[1] <= i[1] and i[2] <= o[2] for o in outs)
+                             for i in ins)
+
+
+class TestProfilerClock:
+    def test_profiler_trace_nests_layer_spans(self, tmp_path):
+        """One api.solve and one Gram-mode api.svd under the JAX profiler,
+        with no recorder: their layer spans are annotations on the
+        profiler's clock, nested by time, the api spans carrying the
+        request id."""
+        A, (b,) = _lstsq(m=256, n=16)
+        R = RowMatrix.create(jnp.asarray(A))
+        sreq = api.SolveRequest(A=R, b=b, loss="quad", tol=1e-6,
+                                max_iters=100)
+        vreq = api.SvdRequest(A=R, k=3, mode="gram")
+
+        def jobs():
+            jax.block_until_ready(api.solve(sreq).x)
+            jax.block_until_ready(api.svd(vreq).factors[1])
+        ev = _profiled(tmp_path, jobs)
+        names = {e[0] for e in ev}
+        assert {"repro.api.solve", "repro.solve.setup", "repro.solve.loop",
+                "repro.planner.plan", "repro.api.svd", "repro.svd.fetch",
+                "repro.svd.eigh", "repro.svd.recover_u"} <= names, names
+        for inner in ("repro.solve.setup", "repro.solve.loop"):
+            assert _inside(ev, "repro.api.solve", inner), inner
+        for inner in ("repro.svd.fetch", "repro.svd.eigh",
+                      "repro.svd.recover_u", "repro.collective.gram"):
+            assert _inside(ev, "repro.api.svd", inner), inner
+        fetch, = [e for e in ev if e[0] == "repro.svd.fetch"]
+        eigh, = [e for e in ev if e[0] == "repro.svd.eigh"]
+        assert fetch[2] <= eigh[1]
+        apis = {e[0]: e[3] for e in ev if e[0].startswith("repro.api.")}
+        assert apis["repro.api.solve"] == {"request_id": sreq.request_id}
+        assert apis["repro.api.svd"] == {"request_id": vreq.request_id}
+        assert "request_id" not in next(
+            e[3] for e in ev if e[0] == "repro.planner.plan")
+
+    def test_null_path_annotates_but_records_nothing(self, tmp_path):
+        A, (b,) = _lstsq()
+        null = telemetry.current()
+        assert null is telemetry.NULL
+        ev = _profiled(tmp_path, lambda: jax.block_until_ready(api.solve(
+            api.SolveRequest(A=A, b=b, loss="quad", tol=1e-6,
+                             max_iters=100)).x))
+        assert {"repro.api.solve", "repro.solve.loop"} <= {e[0] for e in ev}
+        assert null.spans == [] and null.plan_actual() == []
+        assert null.snapshot()["counters"] == {}
+
+    def test_spanless_recorder_annotates(self, tmp_path):
+        """Recorder(spans=False), the server's always-on mode, keeps its
+        metrics and puts its spans on the profiler's clock only."""
+        rec = telemetry.Recorder(spans=False)
+
+        def work():
+            with rec.span("serve.admit", request_id="r-1"):
+                rec.counter("n").inc()
+        ev = _profiled(tmp_path, work)
+        assert [(e[0], e[3]) for e in ev] == [
+            ("repro.serve.admit", {"request_id": "r-1"})]
+        assert rec.spans == [] and rec.counter("n").value == 1
+
+    def test_fused_solve_records_only_the_eager_call(self):
+        """The fused TFOCS engine calls RowMatrix.fused_grad once eagerly
+        (the seed pass) and once under the while_loop's trace: only the
+        eager call writes a span and a plan-vs-actual record."""
+        A, (b,) = _lstsq(m=4096, n=64)
+        rec = telemetry.Recorder()
+        res = api.solve(api.SolveRequest(
+            A=RowMatrix.create(jnp.asarray(A)), b=b, loss="quad",
+            tol=1e-6, max_iters=100, telemetry=rec))
+        assert res.info["plan"] == "fused"
+        grads = [s for s in rec.spans if s.name == "collective.fused_grad"]
+        assert len(grads) == 1 and grads[0].dur_s > 0
+        assert [r["op"] for r in rec.plan_actual()] == ["grad"]
+        by_id = {s.id: s for s in rec.spans}
+        assert by_id[grads[0].parent].name == "solve.loop"
+
+    @pytest.mark.parametrize("recorder", [True, False],
+                             ids=["recorder", "null"])
+    def test_span_under_trace_is_a_named_scope(self, recorder):
+        rec = telemetry.Recorder() if recorder else telemetry.NULL
+
+        def f(x):
+            with rec.span("collective.rmatvec", n=3) as sp:
+                sp.sync_on(x)
+                return jnp.sin(x) * 2
+        text = jax.jit(f).lower(1.0).as_text(debug_info=True)
+        assert "collective.rmatvec" in text
+        assert rec.spans == [] and rec.plan_actual() == []
+
+
 class TestServerMetrics:
     def test_stats_view_and_degraded_breakdown(self):
         """`stats` renders from typed counters, and the degraded count is
@@ -351,8 +451,8 @@ class TestFaultEpisodeTrace:
     def test_span_tree_covers_recovery_phases(self, tmp_path):
         """THE observability acceptance property: a solve that hits an
         injected straggler produces a span tree covering iterate /
-        collective / checkpoint / re-mesh, exportable to Perfetto, with
-        the trip and re-mesh visible as counters."""
+        collective / checkpoint / re-mesh, with the trip and re-mesh
+        visible as counters."""
         from repro.core.distmat.types import make_mesh
         from repro.core.optim.elastic import (ElasticConfig, ElasticGroup,
                                               SolveCheckpoint)
@@ -398,9 +498,9 @@ class TestFaultEpisodeTrace:
             if s.name in ("solver.fused_pass", "solver.remesh"):
                 assert by_id[s.parent].name == "solver.iteration"
 
-        doc = rec.chrome_trace()
-        assert any(e.get("name") == "solver.remesh"
-                   for e in doc["traceEvents"])
+        remesh = [s for s in rec.spans if s.name == "solver.remesh"]
+        assert remesh and all(s.dur_s > 0 and s.t_start_s >= 0
+                              for s in remesh)
 
 
 # =========================================================================
