@@ -10,6 +10,15 @@ All cluster/driver separation from the paper is explicit here:
     bodies — they run on the cluster shards with explicit collectives;
   * vector results (gram output, rmatvec output, stats) come back replicated
     (the "driver" copy, which on a TPU pod is every chip redundantly).
+
+Each body is a module-level function of its static arguments, run as a
+cached jitted program (types.program) keyed by the op, the mesh, the row
+axes and those statics.  An eager shard_map of a fresh closure traces,
+lowers and looks up its program again on every call (and a body with no
+array inputs, the row mask, runs op by op), which cost more host time per
+solve or SVD than the device spent on A; the cached program is dispatched
+by jit's C++ fast path.  Called inside a trace (the solver's loop body) it
+is a nested jit of the same computation.
 """
 from __future__ import annotations
 
@@ -21,6 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.kernels import fusedgrad as _fg
+from repro.kernels import ops as _ops
+from repro.train import compression as _comp
 
 from . import types as T
 
@@ -41,6 +54,155 @@ def chunk_bounds(n: int, chunks: int) -> tuple[tuple[int, int], ...]:
     c = max(min(int(chunks), n), 1)
     step = -(-n // c)
     return tuple((s0, min(s0 + step, n)) for s0 in range(0, n, step))
+
+
+def _out_dtype(dtype):
+    """Logical result dtype of a matrix stored as `dtype`: float32 for
+    sub-f32 storage (bf16/fp8), else the storage dtype."""
+    d = jnp.dtype(dtype)
+    return jnp.dtype(jnp.float32) if d.itemsize < 4 else d
+
+
+# -- shard_map bodies: one function per op makes its body from its statics --
+
+def _mask_body(axes, m, local, dtype):
+    def body():
+        start = _shard_index(axes) * local
+        return ((start + jnp.arange(local)) < m).astype(dtype)
+    return body
+
+
+def _gram_body(axes, n, c):
+    if c <= 1:
+        def body(a):
+            g = _ops.tsgram(a, out_dtype=jnp.float32)
+            return jax.lax.psum(g, axes).astype(_out_dtype(a.dtype))
+    else:
+        bounds = chunk_bounds(n, c)
+
+        def body(a):
+            parts = [jax.lax.psum(
+                _ops.randsketch(a, a[:, s0:s1], out_dtype=jnp.float32),
+                axes) for s0, s1 in bounds]
+            return jnp.concatenate(parts, axis=1).astype(_out_dtype(a.dtype))
+    return body
+
+
+def _matvec_body(axes):
+    def body(a, v):
+        return a @ v
+    return body
+
+
+def _rmatvec_body(axes):
+    def body(a, u):
+        return jax.lax.psum(a.T @ u, axes)
+    return body
+
+
+def _fused_grad_body(axes, nshards, kind, prm, n, c):
+    """The body takes the residual as a fifth argument on the int8 wire
+    (the program's in_specs, part of its key, say which)."""
+    if c <= 1:
+        def body(a, x, t, w, *res):
+            f, g, z = _ops.fused_grad(a, x, t, w, loss=kind, param=prm)
+            if res:
+                g, nres = _comp.psum_int8(g, res[0][0], axes, nshards)
+                return (jax.lax.psum(f, axes), g, z, nres[None])
+            return jax.lax.psum(f, axes), jax.lax.psum(g, axes), z
+    else:
+        bounds = chunk_bounds(n, c)
+
+        def body(a, x, t, w, *res):
+            # Phase 1 — image + row residual, the exact math of
+            # kernels.fusedgrad.fused_grad_jnp (the eager CPU path).
+            z = jnp.dot(a, x, preferred_element_type=jnp.float32)
+            f, r = _fg.row_loss_grad(z, t, w, kind, prm)
+            rc = r.astype(a.dtype) if a.dtype == jnp.float32 else r
+            # Phase 2 — per-segment gradient; segment k's partial psum
+            # overlaps segment k+1's contraction.
+            if res:
+                gs, rs = [], []
+                for s0, s1 in bounds:
+                    part = jnp.dot(rc, a[:, s0:s1],
+                                   preferred_element_type=jnp.float32)
+                    gseg, rseg = _comp.psum_int8(
+                        part, res[0][0, s0:s1], axes, nshards)
+                    gs.append(gseg)
+                    rs.append(rseg)
+                return (jax.lax.psum(f, axes), jnp.concatenate(gs), z,
+                        jnp.concatenate(rs)[None])
+            gs = [jax.lax.psum(
+                jnp.dot(rc, a[:, s0:s1],
+                        preferred_element_type=jnp.float32)
+                .astype(x.dtype), axes) for s0, s1 in bounds]
+            return jax.lax.psum(f, axes), jnp.concatenate(gs), z
+    return body
+
+
+def _fused_grad_multi_body(axes, kind, prm):
+    def body(a, x, t, w):
+        f, g, z = _ops.fused_grad_multi(a, x, t, w, loss=kind, param=prm)
+        return jax.lax.psum(f, axes), jax.lax.psum(g, axes), z
+    return body
+
+
+def _gemm_body(axes):
+    def body(a, b):
+        return _ops.gemm(a, b, out_dtype=a.dtype)
+    return body
+
+
+def _sketch_body(axes, n, r, seed):
+    def body(a):
+        key = jax.random.PRNGKey(seed)       # same key ⇒ same Ω per shard
+        omega = jax.random.normal(key, (n, r), a.dtype)
+        return a @ omega
+    return body
+
+
+def _project_body(axes):
+    def body(a, q):
+        partial = _ops.randsketch(a, q, out_dtype=jnp.float32)
+        return jax.lax.psum(partial, axes)
+    return body
+
+
+def _scale_body(axes):
+    def body(a, d):
+        return a * d[None, :]
+    return body
+
+
+def _stats_body(axes):
+    def body(a, mask):
+        am = a * mask[:, None]
+        s = jax.lax.psum(am.sum(0), axes)
+        sq = jax.lax.psum((am * am).sum(0), axes)
+        nnz = jax.lax.psum((am != 0).sum(0), axes)
+        big = jnp.asarray(jnp.inf, a.dtype)
+        sel_lo = jnp.where(mask[:, None] > 0, a, big)
+        sel_hi = jnp.where(mask[:, None] > 0, a, -big)
+        mn = jax.lax.pmin(sel_lo.min(0), axes)
+        mx = jax.lax.pmax(sel_hi.max(0), axes)
+        return s, sq, nnz, mn, mx
+    return body
+
+
+def _sampled_gram_body(axes, seed):
+    def body(a, p, scale):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                 _shard_index(axes))
+        keep = jax.random.uniform(key, a.shape) < p[None, :]
+        b = jnp.where(keep, a, 0.0) * scale[None, :]
+        return jax.lax.psum(_ops.tsgram(b, out_dtype=jnp.float32), axes)
+    return body
+
+
+def _frobenius_body(axes):
+    def body(a):
+        return jax.lax.psum((a * a).sum(), axes)
+    return body
 
 
 def _record_collective(plan, span, **attrs) -> None:
@@ -94,8 +256,7 @@ class RowMatrix(T.DistMatrix):
         """Logical result dtype: float32 when storage is sub-f32 (bf16/
         fp8) — low-precision residency never narrows the math the caller
         sees."""
-        d = self.rows.dtype
-        return jnp.dtype(jnp.float32) if d.itemsize < 4 else d
+        return _out_dtype(self.rows.dtype)
 
     def astype_store(self, dtype) -> "RowMatrix":
         """Recast the sharded storage (the planner's bf16 pick lands
@@ -110,8 +271,16 @@ class RowMatrix(T.DistMatrix):
     def _spec(self) -> P:
         return P(self.row_axes, None)
 
-    def _smap(self, f, in_specs, out_specs):
-        return T.shard_map(f, self.mesh, in_specs, out_specs)
+    def _program(self, build, in_specs, out_specs, *static):
+        """The cached jitted shard_map program of `build(row_axes,
+        *static)` on this mesh (types.program).  The key is `build`, the
+        mesh, the row axes, the specs and the statics: everything the
+        body closes over."""
+        mesh, axes = self.mesh, self.row_axes
+        return T.program(
+            (build, mesh, axes, in_specs, out_specs) + static,
+            lambda: T.shard_map(build(axes, *static), mesh, in_specs,
+                                out_specs))
 
     def _local_rows(self) -> int:
         return self.rows.shape[0] // T.axes_size(self.mesh, self.row_axes)
@@ -134,15 +303,8 @@ class RowMatrix(T.DistMatrix):
 
     def _row_mask(self) -> Array:
         """Row-sharded {0,1} mask of true (non-padding) rows."""
-        m, nshards = self.n_rows, T.axes_size(self.mesh, self.row_axes)
-        local = self.rows.shape[0] // nshards
-        axes = self.row_axes
-
-        def body():
-            start = _shard_index(axes) * local
-            return ((start + jnp.arange(local)) < m).astype(self.out_dtype)
-
-        return self._smap(body, in_specs=(), out_specs=P(self.row_axes))()
+        return self._program(_mask_body, (), P(self.row_axes), self.n_rows,
+                             self._local_rows(), self.out_dtype)()
 
     # -- cluster matrix ops --------------------------------------------------
     def gram(self, *, chunks: int | str = "auto") -> Array:
@@ -162,57 +324,34 @@ class RowMatrix(T.DistMatrix):
         eager body; "auto" defers to the planner (1 — eager — unless the
         modeled collective dominates the extra A reads).
         """
-        from repro.kernels import ops as _ops
         from repro.launch import telemetry as _tel
-        axes = self.row_axes
         n = self.rows.shape[1]
         plan = self._collective_plan("gram", {"m": self._local_rows(),
                                               "n": n})
         c = self._resolve_chunks(chunks, plan)
-
-        if c <= 1:
-            def body(a):
-                g = _ops.tsgram(a, out_dtype=jnp.float32)
-                return jax.lax.psum(g, axes)
-        else:
-            bounds = chunk_bounds(n, c)
-
-            def body(a):
-                parts = [jax.lax.psum(
-                    _ops.randsketch(a, a[:, s0:s1], out_dtype=jnp.float32),
-                    axes) for s0, s1 in bounds]
-                return jnp.concatenate(parts, axis=1)
-
+        prog = self._program(_gram_body, (self._spec,), P(), n, c)
         with _tel.current().span("collective.gram", op="gram", n=n,
                                  chunks=c) as sp:
-            out = self._smap(body, in_specs=(self._spec,),
-                             out_specs=P())(self.rows)
+            out = prog(self.rows)
             sp.sync_on(out)
         _record_collective(plan, sp, collective="psum", chunks=c)
-        return out.astype(self.out_dtype)
+        return out
 
     def matvec(self, v: Array) -> Array:
         """A v with v replicated (driver) → row-sharded result (cluster)."""
-        def body(a, v):
-            return a @ v
-
-        return self._smap(body, in_specs=(self._spec, P()),
-                          out_specs=P(self.row_axes))(self.rows, v)
+        return self._program(_matvec_body, (self._spec, P()),
+                             P(self.row_axes))(self.rows, v)
 
     def rmatvec(self, u: Array) -> Array:
         """Aᵀ u with u row-sharded → replicated n-vector (back to driver)."""
         from repro.launch import telemetry as _tel
-        axes = self.row_axes
         plan = self._collective_plan("matvec", {"m": self._local_rows(),
                                                 "n": self.rows.shape[1]})
-
-        def body(a, u):
-            return jax.lax.psum(a.T @ u, axes)
-
+        prog = self._program(_rmatvec_body, (self._spec, P(self.row_axes)),
+                             P())
         with _tel.current().span("collective.rmatvec", op="matvec",
                                  n=self.rows.shape[1]) as sp:
-            out = self._smap(body, in_specs=(self._spec, P(self.row_axes)),
-                             out_specs=P())(self.rows, u)
+            out = prog(self.rows, u)
             sp.sync_on(out)
         _record_collective(plan, sp, collective="psum")
         return out
@@ -251,11 +390,7 @@ class RowMatrix(T.DistMatrix):
         all-reduce ships int8, and the quantization error is carried in
         the returned residual for re-injection next call.  Returns a
         4-tuple (f, g, z, new_residual) in that mode."""
-        from repro.kernels import fusedgrad as _fg
-        from repro.kernels import ops as _ops
         from repro.launch import telemetry as _tel
-        from repro.train import compression as _comp
-        axes = self.row_axes
         nshards = T.axes_size(self.mesh, self.row_axes)
         kind, t, w, prm = T.row_separable_inputs(smooth, self.rows.shape[0],
                                                  self._row_mask)
@@ -264,62 +399,22 @@ class RowMatrix(T.DistMatrix):
         plan = self._collective_plan("grad", {"m": self._local_rows(),
                                               "n": n})
         c = self._resolve_chunks(chunks, plan)
-
-        if c <= 1:
-            def body(a, x, t, w, *res):
-                f, g, z = _ops.fused_grad(a, x, t, w, loss=kind, param=prm)
-                if res:
-                    g, nres = _comp.psum_int8(g, res[0][0], axes, nshards)
-                    return (jax.lax.psum(f, axes), g, z, nres[None])
-                return jax.lax.psum(f, axes), jax.lax.psum(g, axes), z
-        else:
-            bounds = chunk_bounds(n, c)
-
-            def body(a, x, t, w, *res):
-                # Phase 1 — image + row residual, the exact math of
-                # kernels.fusedgrad.fused_grad_jnp (the eager CPU path).
-                z = jnp.dot(a, x, preferred_element_type=jnp.float32)
-                f, r = _fg.row_loss_grad(z, t, w, kind, prm)
-                rc = r.astype(a.dtype) if a.dtype == jnp.float32 else r
-                # Phase 2 — per-segment gradient; segment k's partial psum
-                # overlaps segment k+1's contraction.
-                if res:
-                    gs, rs = [], []
-                    for s0, s1 in bounds:
-                        part = jnp.dot(rc, a[:, s0:s1],
-                                       preferred_element_type=jnp.float32)
-                        gseg, rseg = _comp.psum_int8(
-                            part, res[0][0, s0:s1], axes, nshards)
-                        gs.append(gseg)
-                        rs.append(rseg)
-                    return (jax.lax.psum(f, axes), jnp.concatenate(gs), z,
-                            jnp.concatenate(rs)[None])
-                gs = [jax.lax.psum(
-                    jnp.dot(rc, a[:, s0:s1],
-                            preferred_element_type=jnp.float32)
-                    .astype(x.dtype), axes) for s0, s1 in bounds]
-                return jax.lax.psum(f, axes), jnp.concatenate(gs), z
-
+        rows = P(self.row_axes)
+        statics = (nshards, kind, prm, n, c)
         wire = "int8" if residual is not None else "f32"
         with _tel.current().span("collective.fused_grad", op="grad", n=n,
                                  chunks=c, wire=wire) as sp:
             if residual is None:
-                f, g, z = self._smap(
-                    body,
-                    in_specs=(self._spec, P(), P(self.row_axes),
-                              P(self.row_axes)),
-                    out_specs=(P(), P(), P(self.row_axes)))(self.rows, x,
-                                                            t, w)
-                out = (f, g, z)
+                out = self._program(
+                    _fused_grad_body, (self._spec, P(), rows, rows),
+                    (P(), P(), rows), *statics)(self.rows, x, t, w)
             else:
-                f, g, z, nres = self._smap(
-                    body,
-                    in_specs=(self._spec, P(), P(self.row_axes),
-                              P(self.row_axes), self._spec),
-                    out_specs=(P(), P(), P(self.row_axes),
-                               self._spec))(self.rows, x, t, w, residual)
-                out = (f, g, z, nres)
-            sp.sync_on(g)
+                out = self._program(
+                    _fused_grad_body, (self._spec, P(), rows, rows,
+                                       self._spec),
+                    (P(), P(), rows, self._spec),
+                    *statics)(self.rows, x, t, w, residual)
+            sp.sync_on(out[1])
         _record_collective(plan, sp, collective="psum", chunks=c, wire=wire)
         return out
 
@@ -332,34 +427,20 @@ class RowMatrix(T.DistMatrix):
         one loss kind/param (or a single smooth with stacked 2-D targets).
         Returns (replicated (k,) values, replicated (k × n) gradients,
         image sharded (k × m) over the row axes)."""
-        from repro.kernels import ops as _ops
-        axes = self.row_axes
         kind, t, w, prm = T.row_separable_batch_inputs(
             smooths, self.rows.shape[0], self._row_mask)
         x = jnp.atleast_2d(jnp.asarray(x))
-
-        def body(a, x, t, w):
-            f, g, z = _ops.fused_grad_multi(a, x, t, w, loss=kind, param=prm)
-            return jax.lax.psum(f, axes), jax.lax.psum(g, axes), z
-
-        f, g, z = self._smap(
-            body,
-            in_specs=(self._spec, P(), P(None, self.row_axes),
-                      P(None, self.row_axes)),
-            out_specs=(P(), P(), P(None, self.row_axes)))(self.rows, x, t, w)
-        return f, g, z
+        rows = P(None, self.row_axes)
+        return self._program(
+            _fused_grad_multi_body, (self._spec, P(), rows, rows),
+            (P(), P(), rows), kind, prm)(self.rows, x, t, w)
 
     def multiply_local(self, B: Array) -> "RowMatrix":
         """A @ B for a small replicated B — the `U = A (VΣ⁻¹)` pattern:
         broadcast the small factor, then embarrassingly parallel (autotuned
         Pallas GEMM per shard on TPU, jnp reference on CPU)."""
-        from repro.kernels import ops as _ops
-
-        def body(a, b):
-            return _ops.gemm(a, b, out_dtype=a.dtype)
-
-        out = self._smap(body, in_specs=(self._spec, P()),
-                         out_specs=self._spec)(self.rows, B)
+        out = self._program(_gemm_body, (self._spec, P()),
+                            self._spec)(self.rows, B)
         return replace(self, rows=out)
 
     def sketch(self, r: int, *, seed: int = 0) -> "RowMatrix":
@@ -368,15 +449,8 @@ class RowMatrix(T.DistMatrix):
         seed — every chip derives the identical Ω locally, so the sketch
         matrix is never materialized on (or broadcast from) the driver;
         the only HBM traffic is one pass over A."""
-        n = self.rows.shape[1]
-
-        def body(a):
-            key = jax.random.PRNGKey(seed)       # same key ⇒ same Ω per shard
-            omega = jax.random.normal(key, (n, r), a.dtype)
-            return a @ omega
-
-        out = self._smap(body, in_specs=(self._spec,),
-                         out_specs=self._spec)(self.rows)
+        out = self._program(_sketch_body, (self._spec,), self._spec,
+                            self.rows.shape[1], r, seed)(self.rows)
         return replace(self, rows=out)
 
     def project(self, Q: "RowMatrix", *, out_dtype=jnp.float32) -> Array:
@@ -384,46 +458,22 @@ class RowMatrix(T.DistMatrix):
         projection: per-shard streaming cross-Gram (Pallas randsketch
         kernel) then a tree all-reduce over the row axes.  Padding rows are
         zero in both operands so they do not contribute."""
-        from repro.kernels import ops as _ops
-        axes = self.row_axes
-
-        def body(a, q):
-            partial = _ops.randsketch(a, q, out_dtype=jnp.float32)
-            return jax.lax.psum(partial, axes)
-
-        out = self._smap(body, in_specs=(self._spec, self._spec),
-                         out_specs=P())(self.rows, Q.rows)
+        out = self._program(_project_body, (self._spec, self._spec),
+                            P())(self.rows, Q.rows)
         return out.astype(out_dtype)
 
     def scale_columns(self, d: Array) -> "RowMatrix":
         """A · diag(d) with replicated d (DIMSUM column scaling)."""
-        def body(a, d):
-            return a * d[None, :]
-
-        out = self._smap(body, in_specs=(self._spec, P()),
-                         out_specs=self._spec)(self.rows, d)
+        out = self._program(_scale_body, (self._spec, P()),
+                            self._spec)(self.rows, d)
         return replace(self, rows=out)
 
     def column_stats(self) -> dict[str, Array]:
         """Replicated per-column statistics (MLlib colStats)."""
-        axes, m = self.row_axes, self.n_rows
-        mask = self._row_mask()
-
-        def body(a, mask):
-            am = a * mask[:, None]
-            s = jax.lax.psum(am.sum(0), axes)
-            sq = jax.lax.psum((am * am).sum(0), axes)
-            nnz = jax.lax.psum((am != 0).sum(0), axes)
-            big = jnp.asarray(jnp.inf, a.dtype)
-            sel_lo = jnp.where(mask[:, None] > 0, a, big)
-            sel_hi = jnp.where(mask[:, None] > 0, a, -big)
-            mn = jax.lax.pmin(sel_lo.min(0), axes)
-            mx = jax.lax.pmax(sel_hi.max(0), axes)
-            return s, sq, nnz, mn, mx
-
-        s, sq, nnz, mn, mx = self._smap(
-            body, in_specs=(self._spec, P(self.row_axes)),
-            out_specs=(P(), P(), P(), P(), P()))(self.rows, mask)
+        m = self.n_rows
+        s, sq, nnz, mn, mx = self._program(
+            _stats_body, (self._spec, P(self.row_axes)),
+            (P(), P(), P(), P(), P()))(self.rows, self._row_mask())
         mean = s / m
         var = jnp.maximum(sq / m - mean * mean, 0.0) * (m / max(m - 1, 1))
         return {"mean": mean, "variance": var, "num_nonzeros": nnz,
@@ -453,7 +503,6 @@ class RowMatrix(T.DistMatrix):
         computed exactly via one extra Gram over the squared scaled matrix
         — it shrinks to 0 as γ grows (all pᵢ → 1).
         """
-        from repro.kernels import ops as _ops
         norms = self.column_stats()["norm_l2"]
         inv = jnp.where(norms > 0, 1.0 / jnp.maximum(norms, 1e-30), 0.0)
         n = self.shape[1]
@@ -467,17 +516,8 @@ class RowMatrix(T.DistMatrix):
         g = gamma if gamma is not None else dimsum_gamma(n, threshold)
         p = jnp.minimum(1.0, float(np.sqrt(g)) * inv)
         scale = inv * jnp.where(p > 0, 1.0 / p, 0.0)
-        axes = self.row_axes
-
-        def body(a, p, scale):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed),
-                                     _shard_index(axes))
-            keep = jax.random.uniform(key, a.shape) < p[None, :]
-            b = jnp.where(keep, a, 0.0) * scale[None, :]
-            return jax.lax.psum(_ops.tsgram(b, out_dtype=jnp.float32), axes)
-
-        sim = self._smap(body, in_specs=(self._spec, P(), P()),
-                         out_specs=P())(self.rows, p, scale)
+        sim = self._program(_sampled_gram_body, (self._spec, P(), P()),
+                            P(), seed)(self.rows, p, scale)
         sim = sim.astype(self.out_dtype)
         diag = (norms > 0).astype(sim.dtype)
         sim = sim.at[jnp.arange(n), jnp.arange(n)].set(diag)
@@ -507,11 +547,8 @@ class RowMatrix(T.DistMatrix):
                                           row_axes=self.row_axes)
 
     def frobenius_norm(self) -> Array:
-        def body(a):
-            return jax.lax.psum((a * a).sum(), self.row_axes)
-
-        return jnp.sqrt(self._smap(body, in_specs=(self._spec,),
-                                   out_specs=P())(self.rows))
+        return jnp.sqrt(self._program(_frobenius_body, (self._spec,),
+                                      P())(self.rows))
 
     # -- materialization ----------------------------------------------------
     def to_local(self) -> Array:

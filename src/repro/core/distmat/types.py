@@ -8,12 +8,18 @@ agnostic, exactly like MLlib's DistributedMatrix interface.
 
 "Driver-local" quantities (the paper's vectors) are replicated arrays:
 PartitionSpec() over the same mesh.  "Cluster" quantities are sharded.
+
+`program` keeps each distmat shard_map body as one jitted program per
+static signature, so a repeated call runs a compiled program instead of
+tracing and lowering its body again.
 """
 from __future__ import annotations
 
+import collections
 import functools
+import threading
 from dataclasses import dataclass
-from typing import Callable, Sequence
+from typing import Callable, Hashable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -49,9 +55,56 @@ def make_mesh(shape: Sequence[int], names: Sequence[str],
 def shard_map(f: Callable, mesh: Mesh, in_specs, out_specs) -> Callable:
     """jax.shard_map for the distmat bodies.  The Pallas kernels they call
     declare no varying mesh axes on their outputs, so the varying-axes
-    check is off (the out_specs state the layout)."""
+    check is off (the out_specs state the layout).
+
+    Called eagerly, a shard_map traces and lowers its body again on every
+    call (a fresh closure is never recognised as one already run), and a
+    body with no array inputs runs op by op.  RowMatrix therefore runs its
+    bodies as `program`s: jitted once per static signature and kept."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
+
+
+# Jitted programs by static key, least recently used first.
+PROGRAM_CACHE_SIZE = 256
+_programs: "collections.OrderedDict[Hashable, Callable]" = \
+    collections.OrderedDict()
+_programs_lock = threading.Lock()
+
+
+def program(key: Hashable, build: Callable[[], Callable]) -> Callable:
+    """The jitted program kept under `key`, made as ``jax.jit(build())``
+    on a miss.  `key` holds everything `build`'s function closes over (the
+    op, the mesh, the row axes, the static values); array shapes, dtypes
+    and the matmul-precision context are jit's own cache key.  Device
+    arrays are always arguments of the program, never part of `key`, so a
+    cached program neither bakes a matrix in nor keeps one alive.  Later
+    calls take jit's C++ fast path, with no tracing or lowering.
+
+    Counts ``distmat.program`` (result=hit|miss) on the current telemetry
+    recorder; a miss's first call, which traces and compiles, runs inside
+    the span ``distmat.build``.  Holds the last PROGRAM_CACHE_SIZE keys."""
+    from repro.launch import telemetry as _tel
+    rec = _tel.current()
+    with _programs_lock:
+        fn = _programs.get(key)
+        if fn is not None:
+            _programs.move_to_end(key)
+    if fn is not None:
+        rec.counter("distmat.program", result="hit").inc()
+        return fn
+    rec.counter("distmat.program", result="miss").inc()
+    fn = jax.jit(build())
+    with _programs_lock:
+        fn = _programs.setdefault(key, fn)
+        _programs.move_to_end(key)
+        while len(_programs) > PROGRAM_CACHE_SIZE:
+            _programs.popitem(last=False)
+
+    def first_call(*args):
+        with rec.span("distmat.build"):
+            return fn(*args)
+    return first_call
 
 
 def row_axes_for(mesh: Mesh) -> tuple[str, ...]:

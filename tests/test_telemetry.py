@@ -23,6 +23,7 @@ Three layers:
     aliases ("fused", "n_evals", "mode" / "restarts" / "passes_over_A")
     kept for one release.
 """
+import collections
 import json
 import threading
 
@@ -33,6 +34,7 @@ import pytest
 
 from repro import api
 from repro.core.distmat import RowMatrix
+from repro.core.distmat import types as distmat_types
 from repro.launch import machine, telemetry
 
 
@@ -340,6 +342,35 @@ class TestProfilerClock:
         assert apis["repro.api.svd"] == {"request_id": vreq.request_id}
         assert "request_id" not in next(
             e[3] for e in ev if e[0] == "repro.planner.plan")
+
+    def test_programs_build_in_the_first_solve_only(self, tmp_path,
+                                                    monkeypatch):
+        """Two api.solve calls under the profiler, from an empty program
+        cache: one repro.distmat.build span per RowMatrix program built,
+        all inside the first call; the second call builds none."""
+        monkeypatch.setattr(distmat_types, "_programs",
+                            collections.OrderedDict())
+        A, (b,) = _lstsq(m=4096, n=64)
+        req = api.SolveRequest(A=RowMatrix.create(jnp.asarray(A)), b=b,
+                               loss="quad", tol=1e-6, max_iters=100,
+                               telemetry=telemetry.Recorder())
+        first = {}
+
+        def jobs():
+            jax.block_until_ready(api.solve(req).x)
+            first.update(req.telemetry.counters("distmat.program"))
+            jax.block_until_ready(api.solve(req).x)
+        ev = _profiled(tmp_path, jobs)
+        solves = sorted((e for e in ev if e[0] == "repro.api.solve"),
+                        key=lambda e: e[1])
+        builds = [e for e in ev if e[0] == "repro.distmat.build"]
+        assert len(solves) == 2
+        assert len(builds) == first["result=miss"] >= 2
+        assert all(solves[0][1] <= e[1] and e[2] <= solves[0][2]
+                   for e in builds)
+        both = req.telemetry.counters("distmat.program")
+        assert both["result=miss"] == first["result=miss"]
+        assert both["result=hit"] > first.get("result=hit", 0)
 
     def test_null_path_annotates_but_records_nothing(self, tmp_path):
         A, (b,) = _lstsq()
