@@ -1,13 +1,19 @@
 """fig1-dense: a dense float32 RowMatrix drawn on the device from the seed.
 
 `build(cfg, key)` returns the matrix the program is given and the plain
-operators over the same array that the references use.  The draw and the
-reference operators work one row block at a time, so that neither holds a
-second copy of A."""
+references over the same array (the names `bench/loadgen.py` reads).  The
+draw and the reference operators work one row block at a time, so that
+neither holds a second copy of A."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from refs import spectral
+
+# The sizes the benchmark's own tests run this configuration at on the CPU.
+TINY = {"rows": 8192, "cols": 64, "row_block": 2048}
 
 
 def draw(key, m, n, block):
@@ -58,9 +64,34 @@ class Dense:
         self.shape = (cfg["rows"], cfg["cols"])
         self.data = draw(key, *self.shape, self.block)
         self.program = RowMatrix.create(self.data)
+        self._host_gram = None
 
     def ops(self, prec):
         return ops(prec, self.block)
+
+    def host_gram(self):
+        """AᵀA in float64 on the host, formed once, row block by row block."""
+        if self._host_gram is None:
+            self._host_gram = spectral.host_gram(self.data, self.block)
+        return self._host_gram
+
+    def gram_top(self, k):
+        return spectral.host_eigh(self.host_gram(), k)[0]
+
+    def gram_times(self, V):
+        return self.host_gram() @ np.asarray(V, np.float64)
+
+    def times(self, V, prec):
+        mv, _ = self.ops(prec)
+        return jax.jit(mv)(self.data, jnp.asarray(V)[:self.shape[1]])
+
+    def svd_at(self, prec, k):
+        """The Gram row block by row block at `prec`, its eigenpairs on the
+        host, U = A·V·Σ⁻¹ at `prec`."""
+        w, V = spectral.host_eigh(self.gram(prec), k)
+        V = jnp.asarray(V, jnp.float32)
+        s = np.sqrt(w)
+        return self.times(V / jnp.asarray(s, jnp.float32), prec), s, V
 
     def gram(self, prec):
         """AᵀA, one row block at a time."""

@@ -10,7 +10,23 @@ references in `check`.  `control` puts the reference, at a lower matmul
 precision, or the program's own lower-precision path, in the program's
 place on the same sample; the benchmark's runs never call it.
 
-The program is reached only through `repro.api`."""
+The program is reached only through `repro.api`.  Everything else about
+the matrix comes from the object that the configuration's
+`build(cfg, key)` returns, through these names alone:
+
+- every job: `shape` (m, n); `program`, the matrix the entry point is
+  given; `work()`, the sizes the per-layer readers count work from;
+  `lowprec_program()`, the program's own lower-precision storage of the
+  same matrix (the `program_bf16` control only).
+- `job: "solve"`: `data`, the matrix the references read, and
+  `ops(prec)`, the plain operators (mv, rmv) over it at matmul precision
+  `prec`: `mv(data, X)` = A·X, `rmv(data, U)` = Aᵀ·U.
+- `job: "svd"`: `gram_top(k)`, the top-k eigenvalues of AᵀA in float64,
+  largest first (σ's reference is their square root, formed once a run);
+  `gram_times(V)`, AᵀA·V in float64 for V (n, k); `times(V, prec)`, A·V
+  (m, k) at matmul precision `prec`; and `svd_at(prec, k)`, the
+  reference's own (U, σ, V) at matmul precision `prec` (the control).
+  None of them need form an n × n array."""
 from __future__ import annotations
 
 import math
@@ -59,7 +75,8 @@ class Jobs:
 
 class SolveJobs(Jobs):
     """Back-to-back `api.solve` jobs, one family after another in a seeded
-    cycle, each on a label vector from a pool made in set-up."""
+    cycle, each on a label vector from a pool made in set-up, every family
+    on every label in turn."""
 
     metric = "solve_s"
 
@@ -138,12 +155,18 @@ class SolveJobs(Jobs):
         return rec
 
     def run(self, seconds):
-        """Whole cycles of the families until the window has passed."""
+        """Whole cycles of the families until the window has passed.  The
+        label moves on once a cycle, so every family meets every label of
+        the pool once in each pass of len(cycle) × pool jobs: every seed
+        sends the same problems, in another order.  (Moving the label on
+        with every job paired each family with a quarter of the pool, a
+        different quarter for each seed, and so changed the work.)"""
         t0 = time.perf_counter()
         j = 0
         while True:
             fam = self.cycle[j % len(self.cycle)]
-            label = int(self.pool_order[j % len(self.pool_order)])
+            label = int(self.pool_order[
+                j // len(self.cycle) % len(self.pool_order)])
             self.job(fam, label)
             j += 1
             if j % len(self.cycle) == 0 \
@@ -325,29 +348,26 @@ class SvdJobs(Jobs):
         return {self.metric: elapsed / jobs}
 
     def reference(self):
-        """AᵀA in float64 on the host and the square roots of its top-k
-        eigenvalues, formed once."""
+        """σ's reference, formed once: the square roots of AᵀA's top-k
+        eigenvalues in float64."""
         if self.ref is None:
             with span("reference.gram"):
-                G = spectral.host_gram(self.mat.data, self.mat.block)
-                w, _ = spectral.host_eigh(G, self.traffic["k"])
-            self.ref = G, np.sqrt(w)
+                self.ref = np.sqrt(self.mat.gram_top(self.traffic["k"]))
         return self.ref
 
     def compare(self, triples):
         """σ against the reference's; ‖A·V − U·Σ‖/‖A·V‖ with A·V from the
-        reference operator; and ‖G·V − V·Σ²‖/‖V·Σ²‖ with the reference
-        Gram G; each the worst over the triples."""
-        G, s_ref = self.reference()
-        mv, _ = self.mat.ops(HIGHEST)
-        n = self.mat.shape[1]
+        reference; and ‖G·V − V·Σ²‖/‖V·Σ²‖ with G·V = AᵀA·V from the
+        reference in float64; each the worst over the triples."""
+        s_ref = self.reference()
         sigma_gap = u_resid = v_resid = 0.0
         for U, s, V in triples:
             sigma_gap = max(sigma_gap, spectral.rel_gaps(s, s_ref))
             with span("reference.av"):
-                AV = jax.jit(mv)(self.mat.data, jnp.asarray(V)[:n])
+                AV = self.mat.times(V, HIGHEST)
             u_resid = max(u_resid, spectral.factor_residual(AV, U, s))
-            v_resid = max(v_resid, spectral.eigen_residual(G, V, s))
+            v_resid = max(v_resid, spectral.eigen_residual(
+                self.mat.gram_times(V), V, s))
         return {"sigma_gap": sigma_gap, "u_resid": u_resid,
                 "v_resid": v_resid}
 
@@ -357,18 +377,12 @@ class SvdJobs(Jobs):
 
     def control(self, kind, *, diagnose=False):
         """The same comparison with the program's own bfloat16 storage
-        path (`program_bf16`), or with the reference at matmul precision
-        `kind` in the program's place: the Gram row block by row block
-        at that precision, its eigenpairs on the host, U = A·V·Σ⁻¹."""
+        path (`program_bf16`), or with the reference's own triplets at
+        matmul precision `kind` in the program's place."""
         if kind == "program_bf16":
             rec = self.job(record=False, matrix=self.mat.lowprec_program())
             return self.compare([(rec["U"], rec["s"], rec["V"])])
-        mv, _ = self.mat.ops(kind)
-        w, V = spectral.host_eigh(self.mat.gram(kind), self.traffic["k"])
-        V = jnp.asarray(V, jnp.float32)
-        s = np.sqrt(w)
-        U = jax.jit(mv)(self.mat.data, V / jnp.asarray(s, jnp.float32))
-        return self.compare([(U, s, V)])
+        return self.compare([self.mat.svd_at(kind, self.traffic["k"])])
 
 
 def grad0_inf(loss, m, x_inf, x_two, noise):

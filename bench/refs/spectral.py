@@ -4,7 +4,8 @@
 a float32 Gram accumulated by XLA over a million rows, even at precision
 highest, is off by about 2e-5 relative on the chip (PERF.md), which
 would hide the error it is meant to measure.  `host_eigh` is LAPACK in
-float64 on the host."""
+float64 on the host.  Where AᵀA is too large to form, `operator_top`
+finds its top eigenvalues by ARPACK from products with it alone."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -27,6 +28,20 @@ def host_eigh(M, k):
     return w[::-1][:k], V[:, ::-1][:, :k]
 
 
+def operator_top(apply, n, k, *, vectors=False):
+    """Top-k eigenvalues (and with `vectors` eigenvectors) of a symmetric
+    positive semi-definite n × n operator given as `apply(X)` for (n, s)
+    blocks X, largest first: ARPACK (`scipy.sparse.linalg.eigsh`) to
+    machine precision, from a fixed start vector."""
+    from scipy.sparse.linalg import LinearOperator, eigsh
+    op = LinearOperator((n, n), dtype=np.float64,
+                        matvec=lambda x: apply(x.reshape(n, 1))[:, 0],
+                        matmat=apply)
+    w, V = eigsh(op, k, which="LA", tol=0.0, v0=np.ones(n))
+    order = np.argsort(w)[::-1]
+    return (w[order], V[:, order]) if vectors else w[order]
+
+
 def rel_gaps(got, ref):
     """Largest relative gap of each entry against the reference."""
     got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
@@ -40,10 +55,10 @@ def factor_residual(AV, U, s):
     return float(jnp.linalg.norm(US - AV) / jnp.linalg.norm(AV))
 
 
-def eigen_residual(G, V, s):
-    """‖G·V − V·Σ²‖_F / ‖V·Σ²‖_F in float64, with G = AᵀA from the
+def eigen_residual(GV, V, s):
+    """‖G·V − V·Σ²‖_F / ‖V·Σ²‖_F in float64, with G·V = AᵀA·V from the
     reference: a V whose columns are not the eigenvectors of the σ beside
     them shows here, though U = A·V·Σ⁻¹ would pass `factor_residual`."""
     V = np.asarray(V, np.float64)
     VS2 = V * np.asarray(s, np.float64)[None, :] ** 2
-    return float(np.linalg.norm(G @ V - VS2) / np.linalg.norm(VS2))
+    return float(np.linalg.norm(GV - VS2) / np.linalg.norm(VS2))

@@ -13,7 +13,7 @@ import tiny
 def test_bf16_control_is_not_correct(name):
     cell = tiny.R.Cell(tiny.bench(), name)
     line = calibrate.one_seed(cell, 11, 1.0, ["high", "program_bf16"],
-                              sizes=tiny.SIZES["fig1-dense"], diagnose=False)
+                              sizes=tiny.sizes(cell), diagnose=False)
     assert line["correct"], line["program"]
     assert line["control_program_bf16_correct"] is False, line
     checks, correct = tiny.R.judge(line["control_program_bf16"], cell.limits)
